@@ -4,6 +4,7 @@ systems — paper §6.2)."""
 from __future__ import annotations
 
 import copy
+import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -96,3 +97,18 @@ def run_workload(arch: str, system: str, spec: WorkloadSpec, *,
 
 def csv_row(bench: str, name: str, value, derived: str = "") -> str:
     return f"{bench},{name},{value},{derived}"
+
+
+def cpu_rehearsal_env(n_devices: int) -> Dict[str, str]:
+    """Environment for a child process that rehearses a multi-device path
+    on ``n_devices`` emulated CPU devices. JAX is pinned to the CPU: the
+    parent may hold the chip, and a child reaching for it would fail or
+    hang. The device-count flag is appended to the caller's XLA_FLAGS."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = env.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        flags = f"{flags} --xla_force_host_platform_device_count=" \
+            f"{n_devices}".strip()
+    env["XLA_FLAGS"] = flags
+    return env

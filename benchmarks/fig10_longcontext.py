@@ -29,7 +29,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from benchmarks.common import csv_row, run_workload
+from benchmarks.common import cpu_rehearsal_env, csv_row, run_workload
 from repro.serving.workload import WorkloadSpec
 
 STRESS = {
@@ -163,21 +163,13 @@ def _tpot_curve(arch: str, ctx: int, batch: int = 1):
             for sp in (1, 2, 4, 8, 16)}
 
 
-def _force_devices(flags: str) -> str:
-    want = "--xla_force_host_platform_device_count=8"
-    if "xla_force_host_platform_device_count" in flags:
-        return flags
-    return f"{flags} {want}".strip()
-
-
 def _real_engine_row():
     """Reduced-scale real-execution row: the §D12 md-script in a fresh
-    interpreter (8 emulated host devices), its SEQ_PARALLEL_JSON line
-    parsed into the artifact."""
+    interpreter on 8 emulated CPU devices (a rehearsal, never the chip),
+    its SEQ_PARALLEL_JSON line parsed into the artifact."""
     script = os.path.join(os.path.dirname(__file__), "..", "tests",
                           "md_scripts", "check_seq_parallel.py")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = _force_devices(env.get("XLA_FLAGS", ""))
+    env = cpu_rehearsal_env(8)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
     out = subprocess.run([sys.executable, os.path.abspath(script)],
@@ -188,7 +180,9 @@ def _real_engine_row():
                            f"{out.stdout[-3000:]}\n{out.stderr[-3000:]}")
     for ln in out.stdout.splitlines():
         if ln.startswith("SEQ_PARALLEL_JSON "):
-            return json.loads(ln[len("SEQ_PARALLEL_JSON "):])
+            row = json.loads(ln[len("SEQ_PARALLEL_JSON "):])
+            row["backend"] = "cpu (8 emulated devices)"
+            return row
     raise RuntimeError("check_seq_parallel produced no JSON row")
 
 
@@ -250,7 +244,8 @@ def run_guard(out: dict | None = None, real: bool = True):
                             f"{rr['one_engine_pool_tokens']}"))
         rows.append(csv_row("fig10_sp", "real/token_identity",
                             "PASS" if rr["token_identical"] else "FAIL",
-                            "vs big-pool merge-1 reference"))
+                            f"vs big-pool merge-1 reference; "
+                            f"{rr['backend']}"))
         assert rr["token_identical"]
         assert rr["context_tokens"] > rr["one_engine_pool_tokens"]
     rows.append(csv_row("fig10_sp", "guard", "PASS"))

@@ -1,9 +1,10 @@
 """Kernel microbenchmarks: ``name,us_per_call,derived`` rows.
 
-us_per_call: wall-clock of the jnp ORACLE on this CPU host (the Pallas
-kernels are TPU-targeted; interpret mode is a correctness tool, not a
-timing tool). derived: analytic TPU-v5e roofline time for the kernel's
-working set (HBM-bound terms) — what the §Roofline table uses.
+us_per_call: wall-clock per call on the device this runs on (the jnp
+oracles, plus the Pallas kernels where noted). derived: the roofline
+time of the kernel's working set on THAT device, from its published
+peaks (``serving/hardware.peaks_for``); a device without published
+peaks (the CPU) is an error, not a stand-in for the chip.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import csv_row
-from repro.serving.hardware import V5E
+from repro.serving.hardware import peaks_for
 
 
 def _time(fn, *args, iters=5):
@@ -25,6 +26,7 @@ def _time(fn, *args, iters=5):
 
 
 def run():
+    hw = peaks_for(jax.devices()[0].device_kind)
     rows = []
     key = jax.random.key(0)
 
@@ -41,7 +43,7 @@ def run():
                cl)
     hbm = 2 * nblk * page * KV * hd * 2  # k+v pool bytes (bf16 target)
     rows.append(csv_row("kernels", "paged_attention/8x32k_ref", f"{us:.0f}",
-                        f"tpu_roofline_us={hbm / V5E.hbm_bw * 1e6:.0f}"))
+                        f"roofline_us={hbm / hw.hbm_bw * 1e6:.0f}"))
 
     # flash prefill: 2 x 2048 x 4 heads
     from repro.kernels.flash_prefill.ref import flash_prefill_ref
@@ -52,7 +54,7 @@ def run():
     us = _time(jax.jit(lambda *a: flash_prefill_ref(*a)), q2, k2, v2)
     fl = 4 * B2 * H2 * T * T / 2 * hd2
     rows.append(csv_row("kernels", "flash_prefill/2x2048_ref", f"{us:.0f}",
-                        f"tpu_roofline_us={fl / V5E.peak_flops_bf16 * 1e6:.1f}"))
+                        f"roofline_us={fl / hw.peak_flops_bf16 * 1e6:.1f}"))
 
     # the SAME Pallas kernel through the interpreter (small shape — the
     # interpreter re-traces the body per grid step, so this times the
@@ -64,7 +66,7 @@ def run():
     fli = 4 * 1 * H2 * Ti * Ti / 2 * hd2
     rows.append(csv_row(
         "kernels", "flash_prefill/1x256_interp_kernel", f"{us:.0f}",
-        f"tpu_roofline_us={fli / V5E.peak_flops_bf16 * 1e6:.2f}"))
+        f"roofline_us={fli / hw.peak_flops_bf16 * 1e6:.2f}"))
 
     # paged flash-prefill (chunked prefill over the pool, §Perf D6):
     # interpret-mode kernel vs jnp oracle on one chunk with prior context
@@ -74,8 +76,9 @@ def run():
     qp = jax.random.normal(ks[0], (Bp, Tc, H2, hdp), jnp.float32)
     knp = jax.random.normal(ks[1], (Bp, Tc, KVp, hdp), jnp.float32)
     vnp = jax.random.normal(ks[2], (Bp, Tc, KVp, hdp), jnp.float32)
-    kpp = jax.random.normal(ks[3], (nblk, page, KVp, hdp), jnp.float32)
-    vpp = jax.random.normal(ks[4], (nblk, page, KVp, hdp), jnp.float32)
+    # physical pools [nblk, page, KV*hd]
+    kpp = jax.random.normal(ks[3], (nblk, page, KVp * hdp), jnp.float32)
+    vpp = jax.random.normal(ks[4], (nblk, page, KVp * hdp), jnp.float32)
     btp = jax.random.permutation(ks[3], nblk - 1)[:Bp * MBp].reshape(Bp,
                                                                      MBp)
     prior = jnp.full((Bp,), 64, jnp.int32)
@@ -90,7 +93,7 @@ def run():
             prior, iters=3)
         rows.append(csv_row(
             "kernels", f"paged_flash_prefill/2x128c_{impl}", f"{us:.0f}",
-            f"tpu_roofline_us={hbm_p / V5E.hbm_bw * 1e6:.1f}"))
+            f"roofline_us={hbm_p / hw.hbm_bw * 1e6:.1f}"))
 
     # ssd scan: 2 x 2048 x 8 heads
     from repro.kernels.ssd_scan.ref import ssd_scan_ref
@@ -104,7 +107,7 @@ def run():
     us = _time(jax.jit(lambda *a: ssd_scan_ref(*a)), x, dt, A, Bm, Cm, h0)
     fl = 6 * Bs * Ts * Hs * hds * S
     rows.append(csv_row("kernels", "ssd_scan/2x2048_ref", f"{us:.0f}",
-                        f"tpu_roofline_us={fl / V5E.peak_flops_bf16 * 1e6:.2f}"))
+                        f"roofline_us={fl / hw.peak_flops_bf16 * 1e6:.2f}"))
 
     # rglru scan: 2 x 2048 x 1024 channels
     from repro.kernels.rglru_scan.ref import rglru_scan_ref
@@ -115,7 +118,7 @@ def run():
                jnp.zeros((Br, Cr)))
     hbm = 3 * Br * Tr * Cr * 2
     rows.append(csv_row("kernels", "rglru_scan/2x2048_ref", f"{us:.0f}",
-                        f"tpu_roofline_us={hbm / V5E.hbm_bw * 1e6:.1f}"))
+                        f"roofline_us={hbm / hw.hbm_bw * 1e6:.1f}"))
     return rows
 
 

@@ -210,35 +210,31 @@ def run(guard: bool = True):
     return rows
 
 
-def _force_devices(flags: str) -> str:
-    """Append the emulated-device-count flag to whatever XLA_FLAGS the
-    environment already carries (clobbering would drop the caller's
-    flags; setdefault would drop OURS)."""
-    want = "--xla_force_host_platform_device_count=4"
-    if "xla_force_host_platform_device_count" in flags:
-        return flags
-    return f"{flags} {want}".strip()
-
-
 def run_subprocess():
-    """Invoke this module in a fresh interpreter (forcing the emulated
-    device count) and return its CSV rows."""
+    """Invoke this module in a fresh interpreter on 4 emulated CPU
+    devices (a CPU rehearsal, never the chip) and return its CSV rows."""
     import subprocess
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = _force_devices(env.get("XLA_FLAGS", ""))
+
+    from benchmarks.common import cpu_rehearsal_env, csv_row
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__)],
-        env=env, capture_output=True, text=True, timeout=1200)
+        env=cpu_rehearsal_env(4), capture_output=True, text=True,
+        timeout=1200)
     if out.returncode != 0:
         raise RuntimeError(
             f"live_switch microbench failed:\n{out.stdout}\n{out.stderr}")
     return [ln for ln in out.stdout.splitlines()
-            if ln.startswith("live_switch,")]
+            if ln.startswith("live_switch,")] + [
+        csv_row("live_switch", "backend", "cpu",
+                "4 emulated CPU devices: a rehearsal, not a chip run")]
 
 
 if __name__ == "__main__":
-    os.environ["XLA_FLAGS"] = _force_devices(os.environ.get("XLA_FLAGS",
-                                                            ""))
+    if "xla_force_host_platform_device_count" not in \
+            os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                                   " --xla_force_host_platform_device_"
+                                   "count=4").strip()
     for row in run(guard=True):
         print(row)
     print("LIVE SWITCH OK")

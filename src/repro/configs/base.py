@@ -237,7 +237,12 @@ def register(cfg: ArchConfig) -> ArchConfig:
 
 
 def get_config(name: str) -> ArchConfig:
+    """A registered config by name; ``<name>-reduced`` is its smoke-test
+    variant (``ArchConfig.reduced``)."""
     _ensure_loaded()
+    base = name[:-len("-reduced")] if name.endswith("-reduced") else None
+    if base in _REGISTRY:
+        return _REGISTRY[base].reduced()
     if name not in _REGISTRY:
         raise KeyError(f"unknown arch {name!r}; have {sorted(_REGISTRY)}")
     return _REGISTRY[name]

@@ -158,12 +158,9 @@ class CommunicatorPool:
         plain merge island of the same shape.
         """
         island = self._as_island(island)
-        amesh = island_abstract_mesh(self.plan, island.shape)
         sp = island.sp
         key = (island.merge, phase, sampled, donate, batch_bucket,
                seq_bucket, mb_bucket, island.n_engines, live, sp)
-        if amesh is None:  # pragma: no cover - pre-AbstractMesh jax
-            key = key + (island.start,)
         if key not in self._runners:
             if donate:
                 _quiet_unused_donation()
@@ -172,8 +169,7 @@ class CommunicatorPool:
                 phase=phase, window=self.window, use_kernel=self.use_kernel,
                 chunked=(phase == "prefill" and self.chunked),
                 sample=self.sample if sampled else None, live=live, sp=sp,
-                mesh=amesh if amesh is not None
-                else self.island_mesh(island))
+                mesh=island_abstract_mesh(self.plan, island.shape))
             self._runners[key] = jax.jit(
                 run, donate_argnums=(1,) if donate else ())
         return self._runners[key]
@@ -249,22 +245,11 @@ class CommunicatorPool:
         else:
             shapes = tuple(jax.tree.leaves(jax.tree.map(
                 lambda a: (tuple(a.shape), str(a.dtype)), batch)))
-        key = (island.merge, phase, sampled, donate, bb, sb, mb,
-               island.n_engines, shapes)
-        if island_abstract_mesh(self.plan, island.shape) is None:
-            # pre-AbstractMesh fallback: executables are pinned to a
-            # concrete device slice — the cache must not share them
-            # between same-shape islands at different positions
-            key = key + (island.start,)  # pragma: no cover
-        return key
+        return (island.merge, phase, sampled, donate, bb, sb, mb,
+                island.n_engines, shapes)
 
     def memory_overhead_bytes(self) -> int:
         """Analogue of the paper's ~2MB/group measurement: serialized
         executable sizes held by the pool."""
-        total = 0
-        for c in self._compiled.values():
-            try:
-                total += c.memory_analysis().generated_code_size_in_bytes
-            except Exception:
-                pass
-        return total
+        return sum(c.memory_analysis().generated_code_size_in_bytes
+                   for c in self._compiled.values())

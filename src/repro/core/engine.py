@@ -200,6 +200,8 @@ class FlyingEngine:
         # Cleared at the next fleet-wide drain (no pending refs remain).
         self._retired: set = set()
         self._prompt_cache: Dict[str, np.ndarray] = {}
+        # legacy host path (fused_sampling=False): latest logits per request
+        self.last_logits: Dict[str, np.ndarray] = {}
         # recovery-folded prompts: orig prompt ++ harvested tokens. The
         # seed-based regeneration in _prompt_tokens knows nothing about
         # folds, so recovered requests' prompts must be pinned verbatim.
@@ -261,8 +263,11 @@ class FlyingEngine:
 
     def _fresh_states(self, isl: Island, mesh):
         """Island state layout [n, G1=isl.n_engines, G2, *per-device
-        dims]; pools flat. Identical per-device content to the uniform
-        fleet layout — islands only re-scope the group axis."""
+        dims]; paged pools in their physical ``geom.pool_shape()``.
+        Identical per-device content to the uniform fleet layout —
+        islands only re-scope the group axis. Every leaf is created
+        directly with its sharding, so each device allocates only its
+        own slice (never the whole fleet's pool on one device)."""
         cfg = self.cfg
         ctx = make_serving_ctx(isl.merge, self.plan.engine_rows,
                                self.plan.tp_base,
@@ -283,14 +288,16 @@ class FlyingEngine:
                 st = dict(st)
                 if kind[0] in ("gqa", "gqa_win", "mla"):
                     st["mixer"] = tuple(
-                        jax.ShapeDtypeStruct(self.geom.flat_shape(), s.dtype)
+                        jax.ShapeDtypeStruct(self.geom.pool_shape(), s.dtype)
                         for s in st["mixer"])
                 per.append({k: tuple(
-                    jnp.zeros((n, G1, G2) + tuple(s.shape), s.dtype)
+                    jax.ShapeDtypeStruct((n, G1, G2) + tuple(s.shape),
+                                         s.dtype)
                     for s in v) for k, v in st.items()})
             groups.append(tuple(per))
         return jax.tree.map(
-            lambda a: jax.device_put(a, self._state_sharding(a, mesh)),
+            lambda s: jnp.zeros(s.shape, s.dtype,
+                                device=self._state_sharding(s, mesh)),
             groups)
 
     def _assemble_states(self, isl: Island, mesh,
@@ -851,15 +858,9 @@ class FlyingEngine:
                 # decode gathers these first tokens on device by row map
                 self._note_tokens(rt, None, toks_dev, row_reqs)
         else:
-            logits, rt.states = jax.block_until_ready(
-                runner(rt.params, rt.states, batch))
-            for r, row, f in zip(reqs, rows, final):
-                if not f:
-                    continue
-                tok = int(jnp.argmax(logits[row]))
-                self.sync_stats.host_argmax += 1
-                rt.stats.host_argmax += 1
-                self._token_buf.setdefault(r.req_id, []).append(tok)
+            logits, rt.states = runner(rt.params, rt.states, batch)
+            self._host_sample(rt, [(r, row) for r, row, f in
+                                   zip(reqs, rows, final) if f], logits)
         return time.perf_counter() - t0
 
     # ------------------------------------------------------------------
@@ -1349,14 +1350,24 @@ class FlyingEngine:
             toks_dev, rt.states = runner(rt.params, rt.states, batch)
             self._note_tokens(rt, c.key, toks_dev, c.row_reqs)
         else:
-            logits, rt.states = jax.block_until_ready(
-                runner(rt.params, rt.states, batch))
-            for r, row in zip(reqs, c.rows):
-                tok = int(jnp.argmax(logits[row]))
-                self.sync_stats.host_argmax += 1
-                rt.stats.host_argmax += 1
-                self._token_buf.setdefault(r.req_id, []).append(tok)
+            logits, rt.states = runner(rt.params, rt.states, batch)
+            self._host_sample(rt, list(zip(reqs, c.rows)), logits)
         return time.perf_counter() - t0
+
+    def _host_sample(self, rt: _IslandRT, req_rows, logits) -> None:
+        """Legacy host path: one [B, V] logits transfer, greedy argmax
+        per emitting row on the host. Each request's logits row stays in
+        ``last_logits`` until its next token, so reference checks can
+        compare engine variants by logits rather than tokens."""
+        if not req_rows:
+            return
+        host = np.asarray(logits)
+        for r, row in req_rows:
+            self.sync_stats.host_argmax += 1
+            rt.stats.host_argmax += 1
+            self.last_logits[r.req_id] = host[row]
+            self._token_buf.setdefault(r.req_id, []).append(
+                int(host[row].argmax()))
 
     # ------------------------------------------------------------------
     def _prompt_tokens(self, r: Request) -> np.ndarray:

@@ -6,9 +6,13 @@ Physical invariant (paper Eq. 2): per-device block bytes
 merge-m TP group the per-device head slice shrinks to ``kvh_dev/m`` so
 token capacity grows ``B(m) = m * B_base`` (paper Eq. 3 / Alg. 1 step 4:
 ``B_req = B_base*N_eng``, ``H_req = H_base/N_eng``). Device pools are
-stored FLAT ``[num_blocks, block_elems]``; each compiled mode *views*
-them ``[num_blocks, B(m), kvh_dev/m, hd]`` — a metadata reshape, no
-reallocation, no migration.
+stored ``[num_blocks, B_base, token_width]`` (``pool_shape``): one row
+per base-mode token, all of its KV heads — the layout a TPU holds
+without lane padding. Each mode reads it under its head split
+``hs = head_split(m)`` as ``[num_blocks, B_base*hs, kvh_dev/hs, hd]``
+(``view_shape``: view token ``t*hs + j`` is lane group ``j`` of row
+``t``). The paged kernels index that view in place, so a mode switch
+moves no bytes and reshapes nothing.
 
 The host side is the ``LogicalTable``: request -> ordered *segments*,
 each carrying a PLACEMENT TAG ``(mode_tag, shard)`` and the block ids
@@ -188,12 +192,9 @@ class PoolGeometry:
         return (self.num_blocks, self.block_base * hs,
                 self.kvh_dev_base // hs, hd)
 
-    def view(self, flat_pool, merge: int):
-        """Reinterpret the flat physical pool for a mode — pure reshape."""
-        return flat_pool.reshape(flat_pool.shape[:-2] + self.view_shape(merge))
-
-    def flat_shape(self) -> Tuple[int, int]:
-        return (self.num_blocks, self.block_elems)
+    def pool_shape(self) -> Tuple[int, int, int]:
+        """The stored per-device pool: [num_blocks, B_base, token_width]."""
+        return (self.num_blocks, self.block_base, self.token_width)
 
 
 def _v2(n: int) -> int:

@@ -389,15 +389,10 @@ def island_abstract_mesh(plan: ParallelPlan, shape: Tuple[int, int]):
     """Shape-keyed AbstractMesh: every island of (n_engines, merge) shares
     ONE traced step program regardless of which engines it binds (the
     concrete devices resolve from the island-committed params/states at
-    call time). Returns None when this jax lacks AbstractMesh — callers
-    then fall back to per-island concrete meshes."""
-    AbstractMesh = getattr(jax.sharding, "AbstractMesh", None)
-    if AbstractMesh is None:  # pragma: no cover - newer jax always has it
-        return None
+    call time)."""
     n, m = shape
-    return AbstractMesh(
-        (("pod", 1), ("dp", n // m), ("merge", m),
-         ("ed", plan.engine_rows), ("model", plan.tp_base)))
+    return jax.sharding.AbstractMesh(
+        (1, n // m, m, plan.engine_rows, plan.tp_base), MODE_AXES)
 
 
 def enumerate_layouts(plan: ParallelPlan) -> Tuple[FleetLayout, ...]:

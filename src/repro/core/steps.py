@@ -30,15 +30,6 @@ from repro.models.cache import DecodeBackend, PrefillBackend, TrainBackend
 from repro.models.model import Model
 from repro.models.transformer import tp_cross_entropy
 
-# jax >= 0.7 exposes shard_map at top level with `check_vma`; older
-# releases ship it under jax.experimental with the `check_rep` spelling
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax < 0.7 installs
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_KW = {"check_rep": False}
-
 DP_AXES = ("pod", "dp")
 TP_AXES = ("merge", "ed", "model")
 
@@ -141,17 +132,11 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
     ``sample=None`` keeps the logits-returning contract (reference paths
     and consistency tests).
 
-    States layout (engine-owned): each per-layer pool leaf is stored with
-    a leading ``[pod*dp*merge]`` group axis and an ``('ed','model')``-
-    sharded head/width axis is implicit in the per-device flat pools, so
-    every device holds exactly its flat [num_blocks, block_elems] slice:
-    leaf global shape = [L, PODS*DP*MERGE, num_blocks, block_elems],
-    spec P(None, ('pod','dp','merge'), None, ('ed','model'))... For
-    simplicity and exactness we shard the flat elems dim over
-    ('ed','model') — block_elems is per-device already, so the GLOBAL
-    leaf is [L, G, num_blocks, elems*ed*model] and each device sees
-    [L, 1, num_blocks, elems]. Recurrent states: batch over DP axes,
-    feature dim over ('ed','model').
+    States layout (engine-owned): every leaf is ``[L, G1, G2, *per-device
+    dims]`` with G1 sharded over ('pod','dp','merge') and G2 over
+    ('ed','model'), so each device holds exactly its own
+    ``[L, 1, 1, ...]`` slice; for paged pools that slice is the physical
+    ``[num_blocks, B_base, W]`` pool (``PoolGeometry.pool_shape``).
     """
     cfg = model.cfg
     ctx = serving_ctx(mode, cfg)
@@ -164,6 +149,7 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
                                           tp_argmax)
 
     striped = geom.layout == "striped"
+    hs = geom.head_split(merge)
     impl = {None: "auto", True: "force", False: "ref"}[use_kernel]
 
     assert sp >= 1 and merge % sp == 0, (sp, merge)
@@ -198,10 +184,11 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
         (merge, batch_bucket, chunk_bucket, mb_bucket)."""
         assert not striped and cfg.enc_dec is None, \
             "mixed step covers paged attention archs only"
-        sts = _view_states(model, states, geom, merge, flat_to_view=True)
+        sts = _local(states)
         pb = PrefillBackend(
             slots=batch["p_slots"], prior_len=batch["p_prior_len"],
-            block_table=batch["p_block_table"], chunked=True, impl=impl)
+            block_table=batch["p_block_table"], chunked=True, impl=impl,
+            hs=hs)
         logits_p, sts, _ = model.forward(
             params, ctx, mode="prefill", tokens=batch["p_tokens"],
             positions=batch["p_positions"], backend=pb, states=sts,
@@ -224,13 +211,12 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
                          batch["d_tokens"])
         db = DecodeBackend(
             slots=batch["d_slots"], block_table=batch["d_block_table"],
-            context_len=batch["d_context_len"], impl=impl)
+            context_len=batch["d_context_len"], impl=impl, hs=hs)
         logits_d, sts, _ = model.forward(
             params, ctx, mode="decode", tokens=d_in,
             positions=batch["d_positions"], backend=db, states=sts,
             window=window)
-        new_states = _view_states(model, sts, geom, merge,
-                                  flat_to_view=False)
+        new_states = _stacked(sts)
         if sample is not None:
             temp, top_k = sample
             d_toks = sample_tokens(cfg, logits_d[:, -1], ctx,
@@ -241,7 +227,7 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
                 gather_vocab(cfg, logits_d[:, -1], ctx)), new_states
 
     def step(params, states, batch):
-        sts = _view_states(model, states, geom, merge, flat_to_view=True)
+        sts = _local(states)
         if live is not None and phase == "decode":
             from repro.models.cache import LiveDecodeBackend
             backend = LiveDecodeBackend(
@@ -264,7 +250,7 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
         elif phase == "decode":
             backend = DecodeBackend(
                 slots=batch["slots"], block_table=batch["block_table"],
-                context_len=batch["context_len"], impl=impl)
+                context_len=batch["context_len"], impl=impl, hs=hs)
         elif striped:
             from repro.models.striped import StripedPrefillBackend
             backend = StripedPrefillBackend(
@@ -273,15 +259,14 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
             backend = PrefillBackend(
                 slots=batch["slots"], prior_len=batch["prior_len"],
                 block_table=batch["block_table"], chunked=chunked,
-                impl=impl)
+                impl=impl, hs=hs)
         logits, new_sts, _ = model.forward(
             params, ctx, mode=phase, tokens=batch["tokens"],
             positions=batch["positions"], backend=backend, states=sts,
             window=window, enc_len=batch.get("enc_len"),
             frontend_embeds=batch.get("frontend_embeds"),
             last_pos=batch.get("last_pos"))
-        new_states = _view_states(model, new_sts, geom, merge,
-                                  flat_to_view=False)
+        new_states = _stacked(new_sts)
         if sample is not None:
             temp, top_k = sample
             tokens = sample_tokens(cfg, logits[:, -1], ctx,
@@ -307,54 +292,27 @@ def build_serve_step(model: Model, mode: FlyingMode, geom: PoolGeometry, *,
         sspecs = jax.tree.map(lambda a: make_state_spec(a.ndim), states)
         tok_spec = P(DP_AXES,) if sample is not None else P(DP_AXES, None)
         out_spec = (tok_spec, tok_spec) if phase == "mixed" else tok_spec
-        fn = _shard_map(
+        fn = jax.shard_map(
             mixed_step if phase == "mixed" else step, mesh=mesh,
             in_specs=(pspecs, sspecs, bspecs),
-            out_specs=(out_spec, sspecs),
-            **_SM_KW)
+            out_specs=(out_spec, sspecs), check_vma=False)
         return fn(params, states, batch)
 
     return run, mesh, ctx
 
 
-def _view_states(model: Model, states, geom: PoolGeometry, merge: int, *,
-                 flat_to_view: bool):
-    """Mode view <-> physical layout (paper §4.2: a mode switch IS this
-    metadata reshape). Inside shard_map every state leaf arrives as
-    ``[n_layers, 1, 1, *per_device_dims]`` (the two singleton dims are the
-    sharded group/tile axes). flat_to_view squeezes them and reinterprets
-    flat paged pools ``[n, num_blocks, block_elems]`` as the mode view
-    ``[n, num_blocks, B(m), kvh/m, hd]``; the reverse restores physical
-    layout so outputs land back in the invariant pool."""
-    out = []
-    for (kind_seq, n), group in zip(model.plan, states):
-        new_group = []
-        for kind, st in zip(kind_seq, group):
-            mixer = kind[0]
-            st = dict(st)
-            paged = mixer in ("gqa", "gqa_win", "mla")
-            for key in ("mixer", "cross"):
-                if key not in st:
-                    continue
-                leaves = st[key]
-                if flat_to_view:
-                    leaves = tuple(p.reshape((p.shape[0],) + p.shape[3:])
-                                   for p in leaves)
-                    if paged and key == "mixer":
-                        vs = geom.view_shape(merge)
-                        leaves = tuple(p.reshape((p.shape[0],) + vs)
-                                       for p in leaves)
-                else:
-                    if paged and key == "mixer":
-                        leaves = tuple(
-                            p.reshape((p.shape[0],) + geom.flat_shape())
-                            for p in leaves)
-                    leaves = tuple(
-                        p.reshape((p.shape[0], 1, 1) + p.shape[1:])
-                        for p in leaves)
-                st[key] = leaves
-            new_group.append(st)
-        out.append(tuple(new_group))
-    return out
+def _local(states):
+    """shard_map blocks ``[n_layers, 1, 1, *per_device_dims]`` (the two
+    singleton dims are the sharded group/tile axes) -> the per-device
+    ``[n_layers, *per_device_dims]`` states the model threads. Paged
+    pools keep their stored ``[num_blocks, B_base, W]`` layout — the
+    backends read it under the mode's head split (paper §4.2: a mode
+    switch reshapes nothing)."""
+    return jax.tree.map(lambda p: p.reshape((p.shape[0],) + p.shape[3:]),
+                        states)
 
 
+def _stacked(states):
+    """Inverse of ``_local``: outputs land back in the stored layout."""
+    return jax.tree.map(lambda p: p.reshape((p.shape[0], 1, 1) + p.shape[1:]),
+                        states)

@@ -49,14 +49,6 @@ def pow2_shards(n: int, tp: int) -> int:
     return min(1 << v2(n), tp) if n > 0 else 1
 
 
-def _axis_size(ax):
-    """lax.axis_size appeared in newer jax; psum(1) is the portable
-    spelling (the constant folds during lowering)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(ax)
-    return lax.psum(1, ax)
-
-
 @dataclass(frozen=True)
 class TPContext:
     """Static parallel-execution geometry for one compiled mode."""
@@ -79,7 +71,7 @@ class TPContext:
     def _rank_over(self, axes: Tuple[str, ...]):
         r = 0
         for ax in axes:
-            r = r * _axis_size(ax) + lax.axis_index(ax)
+            r = r * lax.axis_size(ax) + lax.axis_index(ax)
         return r
 
     def view_rank(self):

@@ -187,6 +187,13 @@ class WeightsManager:
         specs = self.partition_specs(params_tree, train)
         return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
+    def init_params(self, model, mesh, key):
+        """Seeded random weights created directly in the canonical
+        placement over ``mesh``: every device materializes only its own
+        shard, never the whole model first."""
+        shardings = self.shardings(model.param_specs(), mesh)
+        return jax.jit(model.init, out_shardings=shardings)(key)
+
     # -- zero-copy mode reinterpretation (paper Table 2 '15 ms live') ----
     def reinterpret(self, params, new_mesh, *, check_zero_copy: bool = False):
         """Re-bind the params to another mode mesh. Physically a no-op:

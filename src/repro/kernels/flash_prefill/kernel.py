@@ -116,16 +116,17 @@ def flash_prefill_kernel(q, k, v, *, window: Optional[int] = None,
 # range falls entirely outside [qpos_min - window + 1, qpos_max] are
 # skipped via @pl.when, so per-chunk cost tracks live context
 # (mb-bucket-bounded), not the engine's worst-case table width.
+#
+# Pools arrive PHYSICAL, [nblk, page, W] (kernels/paged_attention/
+# kernel.py): view token t*hs + j of a block is lane group j of row t.
+# Each (lane group, KV head) is a static hd-lane slice of the loaded
+# page, swept by the q heads that read it; every q head keeps its own
+# online-softmax state across all lane groups.
 
-def _paged_kernel(tables_ref, prior_ref, q_ref, k_ref, v_ref,
-                  *out_and_scratch, page: int, blk_q: int, mb: int,
-                  window: Optional[int], softmax_scale: Optional[float],
-                  prior_only: bool, return_lse: bool):
-    if return_lse:
-        out_ref, lse_ref, m_ref, l_ref, acc_ref = out_and_scratch
-    else:
-        out_ref, m_ref, l_ref, acc_ref = out_and_scratch
-        lse_ref = None
+def _paged_kernel(tables_ref, prior_ref, q_ref, k_ref, v_ref, out_ref,
+                  lse_ref, m_ref, l_ref, acc_ref, *, page: int, hs: int,
+                  kvv: int, rep: int, blk_q: int, mb: int,
+                  window: Optional[int], scale: float, prior_only: bool):
     b = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -137,7 +138,8 @@ def _paged_kernel(tables_ref, prior_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    start = j * page
+    cap = page * hs                   # view tokens per block
+    start = j * cap
     q_lo = prior + i * blk_q          # absolute position of first q row
     q_hi = q_lo + blk_q - 1
     if prior_only:
@@ -149,68 +151,79 @@ def _paged_kernel(tables_ref, prior_ref, q_ref, k_ref, v_ref,
     else:
         live = start <= q_hi          # causal: no keys beyond the q block
     if window is not None:
-        live &= start + page > q_lo - window
+        live &= start + cap > q_lo - window
 
     @pl.when(live)
     def _body():
-        q = q_ref[0].astype(jnp.float32)           # [H, blk_q, hd]
-        k = k_ref[0].astype(jnp.float32)           # [page, KV, hd]
-        v = v_ref[0].astype(jnp.float32)
-        H, bq, hd = q.shape
-        KV = k.shape[1]
-        rep = H // KV
-        qf = q.reshape(KV, rep * bq, hd)
-        s = jax.lax.dot_general(
-            qf, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)     # [KV, rep*bq, page]
-        s = s * (softmax_scale if softmax_scale is not None else hd ** -0.5)
-        # flat row f = r*bq + qi within each kv group -> qi = f % bq
-        qpos = q_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (KV, rep * bq, page), 1) % bq
-        kpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (KV, rep * bq, page), 2)
-        if prior_only:
-            mask = kpos < prior
-        else:
-            mask = kpos <= qpos
-        if window is not None:
-            mask &= kpos > qpos - window
-        s = jnp.where(mask, s, NEG_INF)
-
-        sf = s.reshape(H * bq, page)
-        m_prev = m_ref[...]                         # [H*bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sf, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sf - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.reshape(KV, rep * bq, page), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)     # [KV, rep*bq, hd]
-        acc_ref[...] = alpha * acc_ref[...] + pv.reshape(H * bq, hd)
-        m_ref[...] = m_new
+        k_page = k_ref[0].astype(jnp.float32)       # [page, W]
+        v_page = v_ref[0].astype(jnp.float32)
+        hd = acc_ref.shape[-1]
+        shape = (blk_q, page)
+        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        t = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        for jj in range(hs):
+            kpos = start + t * hs + jj
+            mask = (kpos < prior) if prior_only else (kpos <= qpos)
+            if window is not None:
+                mask &= kpos > qpos - window
+            for h in range(kvv):
+                lo = (jj * kvv + h) * hd
+                k = k_page[:, lo:lo + hd]               # [page, hd]
+                v = v_page[:, lo:lo + hd]
+                for r in range(rep):
+                    hq = h * rep + r
+                    q = q_ref[0, hq].astype(jnp.float32)  # [blk_q, hd]
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    s = jnp.where(mask, s * scale, NEG_INF)
+                    m_prev = m_ref[hq]                   # [blk_q, 1]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=-1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+                    l_ref[hq] = alpha * l_ref[hq] + \
+                        jnp.sum(p, -1, keepdims=True)
+                    acc_ref[hq] = alpha * acc_ref[hq] + jnp.dot(
+                        p, v, preferred_element_type=jnp.float32)
+                    m_ref[hq] = m_new
 
     @pl.when(j == mb - 1)
     def _fin():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[0] = out.reshape(out_ref.shape[1:]).astype(out_ref.dtype)
-        if lse_ref is not None:
-            l = l_ref[...]
-            lse = jnp.where(l > 0.0,
-                            m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
-                            NEG_INF)
-            lse_ref[0] = lse.reshape(lse_ref.shape[1:])
+        l = l_ref[...]
+        out_ref[0] = (acc_ref[...] /
+                      jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
+        lse_ref[0] = jnp.where(l > 0.0,
+                               m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
+                               NEG_INF)
+
+
+# query rows (heads x q block) per grid step: bounds the f32 softmax
+# state and acc scratch plus the q/out blocks inside the 16 MiB scoped
+# VMEM default of a v5e core at 32 heads (128 rows per head overflows it)
+MAX_Q_ROWS = 2048
+
+
+def q_block(blk_q: int, T: int, H: int) -> int:
+    """The q block a chunk of T rows over H heads runs with: ``blk_q``
+    capped by T and by the VMEM row budget (a power of two >= 8)."""
+    cap = 8
+    while cap * 2 * H <= MAX_Q_ROWS:
+        cap *= 2
+    return min(blk_q, T, cap)
 
 
 def paged_flash_prefill_kernel(q, k_pool, v_pool, block_table, prior_len, *,
-                               window: Optional[int] = None,
+                               hs: int = 1, window: Optional[int] = None,
                                softmax_scale: Optional[float] = None,
                                blk_q: int = 128, prior_only: bool = False,
                                return_lse: bool = False,
                                interpret: bool = False):
-    """q [B,H,T,hd] (T a multiple of blk_q; absolute position of q[:, :, i]
-    is prior_len[b] + i); pools [nblk,page,KV,hd] already holding the
-    chunk's rows; block_table [B,MB] int32; prior_len [B] int32 ->
-    [B,H,T,hd].
+    """q [B,H,T,hd] (the hs-view's heads; T a multiple of
+    ``q_block(blk_q, T, H)``; absolute
+    position of q[:, :, i] is prior_len[b] + i); pools [nblk, page, W]
+    physical, already holding the chunk's rows; block_table [B,MB]
+    int32; prior_len [B] int32 -> [B,H,T,hd].
 
     ``prior_only`` sweeps a FROZEN block segment (§D8 live reads): every
     query row attends exactly the segment's first ``prior_len[b]``
@@ -218,49 +231,39 @@ def paged_flash_prefill_kernel(q, k_pool, v_pool, block_table, prior_len, *,
     past. ``return_lse`` adds the per-(head, row) log-sum-exp
     [B,H,T] fp32 for LSE-merging this sweep with other segments'."""
     B, H, T, hd = q.shape
-    nblk, page, KV, _ = k_pool.shape
+    nblk, page, W = k_pool.shape
+    kvv = W // (hd * hs)
+    rep = H // kvv
+    assert kvv * hd * hs == W and rep * kvv == H, (q.shape, k_pool.shape, hs)
     MB = block_table.shape[1]
-    blk_q = min(blk_q, T)
+    blk_q = q_block(blk_q, T, H)
     n_q = T // blk_q
-
-    kern = functools.partial(_paged_kernel, page=page, blk_q=blk_q, mb=MB,
-                             window=window, softmax_scale=softmax_scale,
-                             prior_only=prior_only, return_lse=return_lse)
-    out_specs = pl.BlockSpec((1, H, blk_q, hd),
-                             lambda b, i, j, t, p: (b, 0, i, 0))
-    out_shape = jax.ShapeDtypeStruct((B, H, T, hd), q.dtype)
-    if return_lse:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, H * blk_q),
-                                  lambda b, i, j, t, p: (b, i))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B, n_q * H * blk_q), jnp.float32)]
-    out = pl.pallas_call(
+    scale = softmax_scale if softmax_scale is not None else hd ** -0.5
+    kern = functools.partial(_paged_kernel, page=page, hs=hs, kvv=kvv,
+                             rep=rep, blk_q=blk_q, mb=MB, window=window,
+                             scale=scale, prior_only=prior_only)
+    rows = lambda b, i, j, t, p: (b, 0, i, 0)  # noqa: E731
+    page_spec = pl.BlockSpec((1, page, W),
+                             lambda b, i, j, t, p: (t[b, j], 0, 0))
+    out, lse = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_table, prior_len
             grid=(B, n_q, MB),
-            in_specs=[
-                pl.BlockSpec((1, H, blk_q, hd),
-                             lambda b, i, j, t, p: (b, 0, i, 0)),
-                pl.BlockSpec((1, page, KV, hd),
-                             lambda b, i, j, t, p: (t[b, j], 0, 0, 0)),
-                pl.BlockSpec((1, page, KV, hd),
-                             lambda b, i, j, t, p: (t[b, j], 0, 0, 0)),
-            ],
-            out_specs=out_specs,
+            in_specs=[pl.BlockSpec((1, H, blk_q, hd), rows),
+                      page_spec, page_spec],
+            out_specs=[pl.BlockSpec((1, H, blk_q, hd), rows),
+                       pl.BlockSpec((1, H, blk_q, 1), rows)],
             scratch_shapes=[
-                pltpu.VMEM((H * blk_q, 1), jnp.float32),
-                pltpu.VMEM((H * blk_q, 1), jnp.float32),
-                pltpu.VMEM((H * blk_q, hd), jnp.float32),
+                pltpu.VMEM((H, blk_q, 1), jnp.float32),
+                pltpu.VMEM((H, blk_q, 1), jnp.float32),
+                pltpu.VMEM((H, blk_q, hd), jnp.float32),
             ],
         ),
-        out_shape=out_shape,
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32)],
         interpret=interpret,
     )(block_table, prior_len, q, k_pool, v_pool)
     if return_lse:
-        # [B, n_q*H*blk_q] laid out (q_block, head, row) -> [B, H, T]
-        lse = out[1].reshape(B, n_q, H, blk_q)
-        lse = jnp.moveaxis(lse, 2, 1).reshape(B, H, T)
-        return out[0], lse
+        return out, lse[..., 0]
     return out

@@ -12,8 +12,9 @@ attention over prior pages are the same mb-bucket-bounded sweep.
 ``impl`` follows the paged-decode tri-state (``kernel|interpret|ref``,
 resolved by ``kernels/paged_attention/ops.resolve_impl``): the jnp
 reference appends with the scatter oracle and attends via the gathered
-oracle; the kernel path never materializes the gathered context or a
-dense [B,H,Tq,Tk] score tensor.
+oracle over the logical pool view; the kernel path reads the physical
+pool in place and never materializes the gathered context or a dense
+[B,H,Tq,Tk] score tensor.
 """
 from __future__ import annotations
 
@@ -24,10 +25,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_prefill.kernel import (flash_prefill_kernel,
-                                                paged_flash_prefill_kernel)
+                                                paged_flash_prefill_kernel,
+                                                q_block)
 from repro.kernels.flash_prefill.ref import (flash_prefill_ref,
                                              paged_flash_prefill_ref,
                                              paged_prefill_sweep_with_lse_ref)
+from repro.kernels.paged_attention.ref import pool_view
 
 
 def _interpret() -> bool:
@@ -57,84 +60,86 @@ def flash_prefill(q, k, v, *, window: Optional[int] = None, blk: int = 128):
     return jnp.moveaxis(out, 2, 1)
 
 
+def _padded_rows(q, blk_q: int):
+    """q [B,T,H,hd] -> ([B,H,T',hd] padded to the kernel's q block, the
+    q block). Padded rows attend garbage positions past the chunk; their
+    outputs are sliced off by the caller (masked rows keep l>0 via the
+    guarded divide), so they never reach a real row."""
+    B, T, H, hd = q.shape
+    blk = q_block(blk_q, T, H)
+    qt = jnp.moveaxis(q, 1, 2)                       # [B,H,T,hd]
+    pad = (-T) % blk
+    if pad:
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    return qt, blk
+
+
 def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, slots, block_table,
-                        prior_len, *, window: Optional[int] = None,
+                        prior_len, *, hs: int = 1,
+                        window: Optional[int] = None,
                         softmax_scale: Optional[float] = None,
                         blk_q: int = 128, impl: Optional[str] = None):
     """Fused chunk append + paged flash-prefill attention.
 
     q [B,T,H,hd] (row i at absolute position prior_len[b] + i);
-    k_new/v_new [B,T,KV,hd] the chunk's fresh K/V, written at ``slots``
-    [B,T] (negative => parked) before attending; pools [nblk,page,KV,hd]
-    (mode-viewed); block_table [B,MB] covers prior pages AND the chunk's
-    own pages; prior_len [B]. Returns (out [B,T,H,hd], k_pool, v_pool).
+    k_new/v_new [B,T,KV,hd] the chunk's fresh K/V, written at view
+    ``slots`` [B,T] (negative => parked) before attending; pools
+    [nblk,page,W] physical, read under head split ``hs``; block_table
+    [B,MB] covers prior pages AND the chunk's own pages; prior_len [B].
+    Returns (out [B,T,H,hd], k_pool, v_pool).
 
     Called from inside the compiled serve step (no inner jit, same as
     the decode ops — an extra jit boundary would break pool donation).
     """
-    from repro.kernels.paged_attention.ops import resolve_impl
+    from repro.kernels.paged_attention.ops import (paged_append_chunk,
+                                                   resolve_impl)
     impl = resolve_impl(impl)
-    slots = slots.astype(jnp.int32)
+    k_pool, v_pool = paged_append_chunk((k_pool, v_pool), (k_new, v_new),
+                                        slots, hs=hs, impl=impl)
+    T, hd = q.shape[1], q.shape[3]
     if impl == "ref":
-        from repro.kernels.paged_attention.ref import paged_append_chunk_ref
-        k_pool, v_pool = paged_append_chunk_ref(
-            (k_pool, v_pool), (k_new, v_new), slots)
-        out = paged_flash_prefill_ref(q, k_pool, v_pool, block_table,
-                                      prior_len, window=window,
-                                      softmax_scale=softmax_scale)
+        out = paged_flash_prefill_ref(
+            q, pool_view(k_pool, hs, hd), pool_view(v_pool, hs, hd),
+            block_table, prior_len, window=window,
+            softmax_scale=softmax_scale)
         return out, k_pool, v_pool
-    from repro.kernels.paged_attention.kernel import paged_append_chunk_kernel
-    interp = impl == "interpret"
-    k_pool, v_pool = paged_append_chunk_kernel(
-        (k_pool, v_pool), (k_new, v_new), slots, interpret=interp)
-    B, T, H, hd = q.shape
-    qt = jnp.moveaxis(q, 1, 2)                       # [B,H,T,hd]
-    blk_eff = min(blk_q, T)
-    pad = (-T) % blk_eff
-    if pad:
-        # padded q rows attend garbage positions past the chunk; their
-        # outputs are sliced off below (and masked rows keep l>0 via the
-        # guarded divide), so they never reach a real row
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    qt, blk = _padded_rows(q, blk_q)
     out = paged_flash_prefill_kernel(
         qt, k_pool, v_pool, block_table.astype(jnp.int32),
-        prior_len.astype(jnp.int32), window=window,
-        softmax_scale=softmax_scale, blk_q=blk_eff, interpret=interp)
+        prior_len.astype(jnp.int32), hs=hs, window=window,
+        softmax_scale=softmax_scale, blk_q=blk,
+        interpret=(impl == "interpret"))
     return jnp.moveaxis(out[:, :, :T], 2, 1), k_pool, v_pool
 
 
 def paged_prefill_sweep_with_lse(q, k_pool, v_pool, block_table, prior_len,
-                                 *, prior_only: bool = False,
+                                 *, hs: int = 1, prior_only: bool = False,
                                  window: Optional[int] = None,
                                  softmax_scale: Optional[float] = None,
                                  blk_q: int = 128,
                                  impl: Optional[str] = None):
     """Partial chunked-prefill attention over ONE block segment with LSE
-    (§D8 live cross-layout reads). q [B,T,H,hd]; the segment's pages in
-    ``block_table``; ``prior_len`` [B] = tokens of the segment each
-    chunk row may attend (for ``prior_only`` segments: the frozen
-    segment's token count; otherwise the causal current-segment sweep).
-    Returns (out [B,T,H,hd] fp32, lse [B,H,T] fp32); rows/heads with
-    nothing to attend get lse = -inf so an LSE merge ignores them. No
-    append — the live backend writes the chunk separately under the
-    current view."""
+    (§D8 live cross-layout reads). q [B,T,H,hd]; physical pools read
+    under head split ``hs``; the segment's pages in ``block_table``;
+    ``prior_len`` [B] = tokens of the segment each chunk row may attend
+    (for ``prior_only`` segments: the frozen segment's token count;
+    otherwise the causal current-segment sweep). Returns (out
+    [B,T,H,hd] fp32, lse [B,H,T] fp32); rows/heads with nothing to
+    attend get lse = -inf so an LSE merge ignores them. No append — the
+    live backend writes the chunk separately under the current view."""
     from repro.kernels.paged_attention.ops import resolve_impl
     impl = resolve_impl(impl)
+    T, hd = q.shape[1], q.shape[3]
     if impl == "ref":
         return paged_prefill_sweep_with_lse_ref(
-            q, k_pool, v_pool, block_table, prior_len,
-            prior_only=prior_only, window=window,
+            q, pool_view(k_pool, hs, hd), pool_view(v_pool, hs, hd),
+            block_table, prior_len, prior_only=prior_only, window=window,
             softmax_scale=softmax_scale)
-    B, T, H, hd = q.shape
-    qt = jnp.moveaxis(q, 1, 2).astype(jnp.float32)   # [B,H,T,hd]
-    blk_eff = min(blk_q, T)
-    pad = (-T) % blk_eff
-    if pad:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    qt, blk = _padded_rows(q.astype(jnp.float32), blk_q)
     out, lse = paged_flash_prefill_kernel(
         qt, k_pool, v_pool, block_table.astype(jnp.int32),
-        prior_len.astype(jnp.int32), window=window,
-        softmax_scale=softmax_scale, blk_q=blk_eff, prior_only=prior_only,
+        prior_len.astype(jnp.int32), hs=hs, window=window,
+        softmax_scale=softmax_scale, blk_q=blk, prior_only=prior_only,
         return_lse=True, interpret=(impl == "interpret"))
     return (jnp.moveaxis(out[:, :, :T], 2, 1).astype(jnp.float32),
             lse[:, :, :T])

@@ -9,6 +9,14 @@ import jax.numpy as jnp
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def pool_view(pool: jax.Array, hs: int, hd: int) -> jax.Array:
+    """Physical pool [nblk, page, W] -> the head-split-``hs`` logical view
+    [nblk, page*hs, W/(hs*hd), hd] the oracles below read (view token
+    t*hs + j is lane group j of physical row t)."""
+    nblk, page, W = pool.shape
+    return pool.reshape(nblk, page * hs, W // (hs * hd), hd)
+
+
 def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         block_table: jax.Array, context_len: jax.Array, *,
                         window: Optional[int] = None,

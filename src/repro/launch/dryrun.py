@@ -118,7 +118,7 @@ def abstract_states(model: Model, geom: PoolGeometry, mode: FlyingMode,
                                    make=jax.ShapeDtypeStruct)
             st = dict(st)
             if kind[0] in ("gqa", "gqa_win", "mla"):
-                st["mixer"] = tuple(S(geom.flat_shape(), s.dtype)
+                st["mixer"] = tuple(S(geom.pool_shape(), s.dtype)
                                     for s in st["mixer"])
             per.append({k: tuple(S((n, G1, G2) + tuple(s.shape), s.dtype)
                                  for s in v) for k, v in st.items()})

@@ -3,9 +3,12 @@
 Modes:
   --sim  (default): full-scale discrete-event run on the roofline cost
          model — the production mesh geometry, any arch, paper workloads.
-  --real: actual execution of reduced configs on local devices (set
-         XLA_FLAGS=--xla_force_host_platform_device_count=8 to emulate a
-         small fleet on CPU).
+  --real: the FlyingEngine on the local devices — the named config at
+         its own widths in bf16, one engine per device (one TPU v5e chip
+         serves stablelm-1.6b), the KV pool sized to the HBM the weights
+         leave free. CPU runs pick a reduced config by name (e.g.
+         --arch stablelm-1.6b-reduced) and may emulate a fleet with
+         XLA_FLAGS=--xla_force_host_platform_device_count=8.
   --serve: boot the §D13 async serving core — the OpenAI-style HTTP/SSE
          endpoint (`serving/server.py`) over the event-driven
          continuous-batching loop — instead of replaying a trace.
@@ -144,6 +147,38 @@ def parse_config(argv=None) -> ServeConfig:
 # stack construction
 # ---------------------------------------------------------------------------
 
+# the real engine's deployment shape: 16 concurrent decodes per engine,
+# 256-token prefill chunks, requests up to 4096 tokens (prompt + output,
+# stablelm-2's context), 16-token KV blocks
+DECODE_BATCH = 16
+PREFILL_CHUNK = 256
+MAX_CONTEXT = 4096
+BLOCK_TOKENS = 16
+ACT_HEADROOM = 1 << 30     # per-device bytes left for a step's activations
+
+
+def kv_pool_blocks(model, geom, demand: int) -> int:
+    """KV blocks per device: what device memory holds once the (already
+    resident) weights and ``ACT_HEADROOM`` are taken out, capped at
+    ``demand`` — the blocks a full batch of max-context requests can
+    address. Each step program also keeps one layer's K/V pool slice as
+    scratch, hence the (1 + 1/layers) factor. A backend that reports no
+    memory limit (the CPU) gets the cap."""
+    import jax
+    import jax.numpy as jnp
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return demand
+    leaves = sum(n * (1 if kind[0] == "mla" else 2)
+                 for kind_seq, n in model.plan for kind in kind_seq
+                 if kind[0] in ("gqa", "gqa_win", "mla"))
+    per_block = leaves * geom.block_base * geom.token_width \
+        * jnp.dtype(model.dtype).itemsize
+    free = stats["bytes_limit"] - stats["bytes_in_use"] - ACT_HEADROOM
+    fit = int(free / (per_block * (1 + 1 / model.cfg.num_layers)))
+    return max(min(demand, fit), 0)
+
+
 def build_stack(cfg: ServeConfig):
     """Scheduler + workload spec for this config (sim or real)."""
     from repro.configs import get_config
@@ -164,24 +199,36 @@ def build_stack(cfg: ServeConfig):
 
     if cfg.real:
         import jax
-        import jax.numpy as jnp
         from repro.core.engine import FlyingEngine
+        from repro.core.modes import FlyingMode, mode_mesh
+        from repro.core.weights_manager import WeightsManager
         from repro.models.model import build_model
-        n = len(jax.devices())
-        assert n >= 4, "run with XLA_FLAGS=--xla_force_host_platform" \
-                       "_device_count=8 for a local fleet"
-        mcfg = get_config(cfg.arch).reduced()
-        plan = ParallelPlan(engine_rows=1, tp_base=2, data_rows=n // 2)
-        geom = PoolGeometry(mcfg, plan, num_blocks=64, block_base=4)
-        model = build_model(mcfg, jnp.float32)
-        params = model.init(jax.random.key(0))
+        mcfg = get_config(cfg.arch)
+        # one engine per device: DP across devices, TP by merging engines
+        plan = ParallelPlan(engine_rows=1, tp_base=1,
+                            data_rows=len(jax.devices()))
+        model = build_model(mcfg)                       # bf16 weights
+        params = WeightsManager(mcfg, plan).init_params(
+            model, mode_mesh(FlyingMode(plan, 1)), jax.random.key(cfg.seed))
+        max_blocks = -(-MAX_CONTEXT // BLOCK_TOKENS)
+        blocks = kv_pool_blocks(
+            model, PoolGeometry(mcfg, plan, 1, BLOCK_TOKENS),
+            demand=DECODE_BATCH * max_blocks + 1)
+        geom = PoolGeometry(mcfg, plan, num_blocks=blocks,
+                            block_base=BLOCK_TOKENS)
+        print(f"kv pool: {blocks} blocks x {BLOCK_TOKENS} tokens per "
+              f"device ({blocks * BLOCK_TOKENS} tokens), "
+              f"{len(jax.devices())} engine(s)")
         backend = FlyingEngine(model, plan, geom, params,
-                               batch_per_engine=2, prefill_len=8,
+                               batch_per_engine=DECODE_BATCH,
+                               max_blocks_per_req=max_blocks,
+                               prefill_len=PREFILL_CHUNK,
                                injector=injector)
         sched = DynamicScheduler(
             plan, geom, backend,
-            SchedulerConfig(strategy=cfg.strategy, max_batch_per_group=2,
-                            prefill_chunk=8,
+            SchedulerConfig(strategy=cfg.strategy,
+                            max_batch_per_group=DECODE_BATCH,
+                            prefill_chunk=PREFILL_CHUNK,
                             prefix_cache=cfg.prefix_cache,
                             fixed_merge=cfg.fixed_merge or None),
             policy=cfg.policy())
@@ -191,14 +238,14 @@ def build_stack(cfg: ServeConfig):
             # issues a transition for fixed_merge runs
             backend.switch(1, cfg.fixed_merge)
         spec = WorkloadSpec(n_requests=cfg.requests, seed=cfg.seed,
-                            prompt_range=(8, 8), output_range=(4, 8),
+                            prompt_range=(128, 2048), output_range=(32, 128),
                             low_rate=(20, 50), burst_rate=(100, 200),
                             phase_seconds=0.5,
                             priority_frac=cfg.priority_frac)
         if cfg.prefix_cache:
             spec.prefix_pool = cfg.prefix_pool
             spec.prefix_hit = cfg.prefix_hit
-            spec.prefix_range = (4, 8)
+            spec.prefix_range = (64, 512)
     else:
         mcfg = get_config(cfg.arch)
         plan = ParallelPlan(engine_rows=mcfg.engine_rows, tp_base=16,
@@ -378,6 +425,9 @@ def run_offline(cfg: ServeConfig) -> None:
 
 def main(argv=None):
     cfg = parse_config(argv)
+    if cfg.real:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if cfg.serve:
         serve_http(cfg)
     else:

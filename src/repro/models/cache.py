@@ -9,10 +9,13 @@ and threads a per-layer ``state`` pytree through ``lax.scan``. Backends:
   previously cached pages: chunked prefill), writes new KV into pages.
 - ``DecodeBackend``    — single-token append + paged attention over the pool.
 
-Paged states are the *mode-viewed* arrays produced by the KV Cache Adaptor
-(core/kv_adaptor.py): per layer ``k/v: [num_blocks, page, kvh_local, hd]``
-(or ``[num_blocks, page, width]`` for compressed MLA caches). Physical
-pool bytes are mode-invariant; only this view changes (paper §4.2).
+Paged states are the PHYSICAL per-device pools of the KV Cache Adaptor
+(core/kv_adaptor.py): per layer ``k/v: [num_blocks, B_base, W]`` with
+``W`` the row of all base-mode KV heads (or ``[num_blocks, page,
+width]`` for compressed MLA caches). A mode reads them under its head
+split ``hs`` (view token ``t*hs + j`` = lane group ``j`` of row ``t``);
+the kernels index that in place and the references reshape to the
+logical view ``[num_blocks, B_base*hs, kvh/hs, hd]`` (paper §4.2).
 """
 from __future__ import annotations
 
@@ -22,6 +25,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.kernels.paged_attention.ref import pool_view
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -182,6 +187,7 @@ class PrefillBackend:
     block_table: jax.Array
     chunked: bool = False
     impl: Optional[str] = None
+    hs: int = 1               # head split of the mode reading the pools
 
     def attend(self, state, q, k, v, *, positions, window=None):
         from repro.kernels.paged_attention import ops as pa_ops
@@ -190,15 +196,16 @@ class PrefillBackend:
             from repro.kernels.flash_prefill import ops as fp_ops
             out, k_pool, v_pool = fp_ops.paged_flash_prefill(
                 q, k, v, k_pool, v_pool, self.slots, self.block_table,
-                self.prior_len, window=window, impl=self.impl)
+                self.prior_len, hs=self.hs, window=window, impl=self.impl)
             return out, (k_pool, v_pool)
-        k_pool = paged_append(k_pool, k, self.slots)
-        v_pool = paged_append(v_pool, v, self.slots)
         hd = q.shape[-1]
+        kv = paged_append(pool_view(k_pool, self.hs, hd), k, self.slots)
+        vv = paged_append(pool_view(v_pool, self.hs, hd), v, self.slots)
+        new_state = (kv.reshape(k_pool.shape), vv.reshape(v_pool.shape))
         scale = hd ** -0.5
         if not self.chunked:
             out = causal_attention(q, k, v, window=window)
-            return out, (k_pool, v_pool)
+            return out, new_state
         # chunked reference: merge in-chunk causal with attention over
         # prior pages
         B, Tq = q.shape[0], q.shape[1]
@@ -209,8 +216,8 @@ class PrefillBackend:
             inmask = inmask & (jnp.arange(Tq)[None, None, :] >
                                jnp.arange(Tq)[None, :, None] - window)
         o1, l1 = attention_with_lse(q, k, v, inmask[:, None], scale)
-        kp = paged_gather(k_pool, self.block_table)
-        vp = paged_gather(v_pool, self.block_table)
+        kp = paged_gather(kv, self.block_table)
+        vp = paged_gather(vv, self.block_table)
         Tk = kp.shape[1]
         pmask = jnp.arange(Tk)[None, None, None, :] < \
             self.prior_len[:, None, None, None]
@@ -219,7 +226,7 @@ class PrefillBackend:
                              qpos[:, None, :, 0:1] - window + 1)
         o2, l2 = attention_with_lse(q, kp, vp, pmask, scale)
         out = merge_attention([(o1, l1), (o2, l2)])
-        return out.astype(q.dtype), (k_pool, v_pool)
+        return out.astype(q.dtype), new_state
 
     def append_ctx(self, state, vals, *, positions):
         (pool,) = state if isinstance(state, tuple) and len(state) == 1 \
@@ -244,6 +251,7 @@ class DecodeBackend:
     block_table: jax.Array    # [B, max_blocks] (mb-bucketed width)
     context_len: jax.Array    # [B] incl. the new token
     impl: Optional[str] = None
+    hs: int = 1               # head split of the mode reading the pools
 
     def attend(self, state, q, k, v, *, positions, window=None):
         from repro.kernels.paged_attention import ops as pa_ops
@@ -254,16 +262,20 @@ class DecodeBackend:
             # materializes repeated/fp32 copies of the gathered context
             # (§Perf A1) — the kernels-local oracle does, and is a test
             # oracle, not a serving path
-            k_pool = paged_append(k_pool, k, self.slots[:, None])
-            v_pool = paged_append(v_pool, v, self.slots[:, None])
-            out = paged_attention_ref(q[:, 0], k_pool, v_pool,
-                                      self.block_table, self.context_len,
-                                      window=window)
+            hd = q.shape[-1]
+            kv = paged_append(pool_view(k_pool, self.hs, hd), k,
+                              self.slots[:, None])
+            vv = paged_append(pool_view(v_pool, self.hs, hd), v,
+                              self.slots[:, None])
+            out = paged_attention_ref(q[:, 0], kv, vv, self.block_table,
+                                      self.context_len, window=window)
+            k_pool, v_pool = kv.reshape(k_pool.shape), vv.reshape(
+                v_pool.shape)
         else:
             out, k_pool, v_pool = pa_ops.paged_attention_decode(
                 q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool, self.slots,
-                self.block_table, self.context_len, window=window,
-                impl=self.impl)
+                self.block_table, self.context_len, hs=self.hs,
+                window=window, impl=self.impl)
         return out[:, None], (k_pool, v_pool)
 
     def attend_mla_absorbed(self, state, q_abs, q_pe, entry, *, R: int,
@@ -400,18 +412,9 @@ class LiveDecodeBackend:
                                              kv_loc, 1)
             v_new = lax.dynamic_slice_in_dim(v[:, 0], v_idx * kv_loc,
                                              kv_loc, 1)
-            if pa_ops.resolve_impl(self.impl) == "ref":
-                k_pool = paged_append(k_pool, k_new[:, None],
-                                      self.slots[:, None])
-                v_pool = paged_append(v_pool, v_new[:, None],
-                                      self.slots[:, None])
-            else:
-                from repro.kernels.paged_attention.kernel import \
-                    paged_append_token_kernel
-                interp = pa_ops.resolve_impl(self.impl) == "interpret"
-                k_pool, v_pool = paged_append_token_kernel(
-                    (k_pool, v_pool), (k_new, v_new), self.slots,
-                    interpret=interp)
+            k_pool, v_pool = pa_ops.paged_append_token(
+                (k_pool, v_pool), (k_new, v_new), self.slots, hs=m,
+                impl=self.impl)
         else:
             # §D12 sequence-parallel write: shard-width tag, per-row
             # owner shard. The parking (non-owner ranks write the
@@ -430,39 +433,22 @@ class LiveDecodeBackend:
             park = nb * cap_w - 1   # last slot of the reserved block
             slots_w = jnp.where(is_owner, self.slots, park).astype(
                 self.slots.dtype)
-            kp_w = k_pool.reshape(nb, cap_w, kvh_w, hd)
-            vp_w = v_pool.reshape(nb, cap_w, kvh_w, hd)
-            if pa_ops.resolve_impl(self.impl) == "ref":
-                kp_w = paged_append(kp_w, k_new[:, None], slots_w[:, None])
-                vp_w = paged_append(vp_w, v_new[:, None], slots_w[:, None])
-            else:
-                from repro.kernels.paged_attention.kernel import \
-                    paged_append_token_kernel
-                interp = pa_ops.resolve_impl(self.impl) == "interpret"
-                kp_w, vp_w = paged_append_token_kernel(
-                    (kp_w, vp_w), (k_new, v_new), slots_w,
-                    interpret=interp)
-            k_pool = kp_w.reshape(k_pool.shape)
-            v_pool = vp_w.reshape(v_pool.shape)
+            k_pool, v_pool = pa_ops.paged_append_token(
+                (k_pool, v_pool), (k_new, v_new), slots_w, hs=wt,
+                impl=self.impl)
 
-        flat_k = k_pool.reshape(nb, -1)
-        flat_v = v_pool.reshape(nb, -1)
         q_st = q[:, 0]                           # [B, H_st, hd]
         partials = []
         for tag, bt_t, len_t, own_t in self.segs:
-            cap_t = self.block_base * tag
-            kvh_t = KV_st // tag
             Hq_t = H_st // tag
-            view_k = flat_k.reshape(nb, cap_t, kvh_t, hd)
-            view_v = flat_v.reshape(nb, cap_t, kvh_t, hd)
             ok = (own_t <= v_idx) & (v_idx < own_t + tag)       # [B]
             eff = jnp.where(ok, len_t, 0).astype(jnp.int32)
             v_old = jnp.clip(v_idx - own_t, 0, tag - 1)
             idx = v_old[:, None] * Hq_t + jnp.arange(Hq_t)[None, :]
             q_sub = jnp.take_along_axis(q_st, idx[:, :, None], axis=1)
             out_t, lse_t = pa_ops.paged_attention_with_lse(
-                q_sub, view_k, view_v, bt_t, eff, softmax_scale=scale,
-                impl=self.impl)
+                q_sub, k_pool, v_pool, bt_t, eff, hs=tag,
+                softmax_scale=scale, impl=self.impl)
             partials.append(_seg_scatter(out_t, lse_t, v_old,
                                          ok & (len_t > 0), H_st, 1))
         m_loc, ws, l_loc = _merge_sweeps(partials)
@@ -514,16 +500,9 @@ class LivePrefillBackend:
             kv_loc = KV_st // m
             k_new = lax.dynamic_slice_in_dim(k, v_idx * kv_loc, kv_loc, 2)
             v_new = lax.dynamic_slice_in_dim(v, v_idx * kv_loc, kv_loc, 2)
-            if pa_ops.resolve_impl(self.impl) == "ref":
-                k_pool = paged_append(k_pool, k_new, self.slots)
-                v_pool = paged_append(v_pool, v_new, self.slots)
-            else:
-                from repro.kernels.paged_attention.kernel import \
-                    paged_append_chunk_kernel
-                interp = pa_ops.resolve_impl(self.impl) == "interpret"
-                k_pool, v_pool = paged_append_chunk_kernel(
-                    (k_pool, v_pool), (k_new, v_new), self.slots,
-                    interpret=interp)
+            k_pool, v_pool = pa_ops.paged_append_chunk(
+                (k_pool, v_pool), (k_new, v_new), self.slots, hs=m,
+                impl=self.impl)
         else:
             # §D12: shard-width owner-masked chunk write (the engine
             # guarantees each row's chunk lies within ONE block, so one
@@ -541,30 +520,13 @@ class LivePrefillBackend:
             park = nb * cap_w - 1
             slots_w = jnp.where(is_owner[:, None] & (self.slots >= 0),
                                 self.slots, park).astype(self.slots.dtype)
-            kp_w = k_pool.reshape(nb, cap_w, kvh_w, hd)
-            vp_w = v_pool.reshape(nb, cap_w, kvh_w, hd)
-            if pa_ops.resolve_impl(self.impl) == "ref":
-                kp_w = paged_append(kp_w, k_new, slots_w)
-                vp_w = paged_append(vp_w, v_new, slots_w)
-            else:
-                from repro.kernels.paged_attention.kernel import \
-                    paged_append_chunk_kernel
-                interp = pa_ops.resolve_impl(self.impl) == "interpret"
-                kp_w, vp_w = paged_append_chunk_kernel(
-                    (kp_w, vp_w), (k_new, v_new), slots_w,
-                    interpret=interp)
-            k_pool = kp_w.reshape(k_pool.shape)
-            v_pool = vp_w.reshape(v_pool.shape)
+            k_pool, v_pool = pa_ops.paged_append_chunk(
+                (k_pool, v_pool), (k_new, v_new), slots_w, hs=wt,
+                impl=self.impl)
 
-        flat_k = k_pool.reshape(nb, -1)
-        flat_v = v_pool.reshape(nb, -1)
         partials = []
         for i, (tag, bt_t, len_t, own_t) in enumerate(self.segs):
-            cap_t = self.block_base * tag
-            kvh_t = KV_st // tag
             Hq_t = H_st // tag
-            view_k = flat_k.reshape(nb, cap_t, kvh_t, hd)
-            view_v = flat_v.reshape(nb, cap_t, kvh_t, hd)
             ok = (own_t <= v_idx) & (v_idx < own_t + tag)
             eff = jnp.where(ok, len_t, 0).astype(jnp.int32)
             v_old = jnp.clip(v_idx - own_t, 0, tag - 1)
@@ -573,8 +535,8 @@ class LivePrefillBackend:
             cur = (tag == m) if self.sp == 1 \
                 else (i == len(self.segs) - 1)
             out_t, lse_t = fp_ops.paged_prefill_sweep_with_lse(
-                q_sub, view_k, view_v, bt_t, eff, prior_only=not cur,
-                softmax_scale=scale, impl=self.impl)
+                q_sub, k_pool, v_pool, bt_t, eff, hs=tag,
+                prior_only=not cur, softmax_scale=scale, impl=self.impl)
             # the causal-lane sweep is causal over [prior, prior+T): it
             # always contributes (the chunk row itself, on the owner
             # ranks); other lanes only where the lane exists

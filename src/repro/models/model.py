@@ -87,10 +87,10 @@ class Model:
         hd = cfg.resolved_head_dim
         st: Dict[str, Any] = {}
         if mixer in ("gqa", "gqa_win"):
-            KVl = ctx.local_units(cfg.num_kv_heads)
-            pool = make((num_blocks, page, KVl, hd), self.dtype)
-            st["mixer"] = (pool, make((num_blocks, page, KVl, hd),
-                                      self.dtype))
+            # physical pool rows: all local KV heads of one token
+            W = ctx.local_units(cfg.num_kv_heads) * hd
+            st["mixer"] = (make((num_blocks, page, W), self.dtype),
+                           make((num_blocks, page, W), self.dtype))
         elif mixer == "mla":
             w = mla_cache_width(cfg)
             st["mixer"] = (make((num_blocks, page, w), self.dtype),)

@@ -77,8 +77,8 @@ def _partial_attention(q, k, v, valid, scale):
 @dataclass(frozen=True)
 class StripedDecodeBackend:
     """Decode over the striped pool. State per layer: (k_pool, v_pool)
-    viewed [nblk, page, KV_full, hd] (GQA) or (pool,) [nblk, page, W]
-    (MLA, via attend_mla)."""
+    stored [nblk, page, KV_full*hd] (GQA; read as [nblk, page, KV_full,
+    hd]) or (pool,) [nblk, page, W] (MLA, via attend_mla)."""
     ctx: TPContext
     block_table: jax.Array   # [B, MB]
     context_len: jax.Array   # [B] incl. current token
@@ -94,11 +94,10 @@ class StripedDecodeBackend:
         """q [B,1,Hl,hd]; k/v [B,1,KVl,hd] (local heads, new token)."""
         tctx = self.ctx
         cfg_window = window if window is not None else self.window
-        k_pool, v_pool = state
-        page = k_pool.shape[1]
-        B = q.shape[0]
-        H_total_l = q.shape[2]
         hd = q.shape[-1]
+        stored = state[0].shape
+        k_pool, v_pool = (p.reshape(stored[:2] + (-1, hd)) for p in state)
+        page = k_pool.shape[1]
         stripe, F = self._stripe()
 
         # 1. gather new-token kv to full heads; write my stripe's tokens
@@ -136,7 +135,8 @@ class StripedDecodeBackend:
         wire = k_pool.dtype if k_pool.dtype == jnp.bfloat16 else None
         out_full = tctx.lse_merge(acc, l, m, wire_dtype=wire)  # [B,H,hd]
         out = _take_local_heads(tctx, out_full, self.n_q_heads)
-        return out[:, None].astype(q.dtype), (k_pool, v_pool)
+        return out[:, None].astype(q.dtype), (k_pool.reshape(stored),
+                                              v_pool.reshape(stored))
 
     # ---- MLA absorbed path ------------------------------------------------
     def attend_mla(self, state, q_abs, q_pe, cache_entry, *, R: int,
@@ -199,7 +199,9 @@ class StripedPrefillBackend:
 
     def attend(self, state, q, k, v, *, positions, window=None):
         from repro.models.cache import causal_attention
-        k_pool, v_pool = state
+        stored = state[0].shape
+        k_pool, v_pool = (p.reshape(stored[:2] + (-1, q.shape[-1]))
+                          for p in state)
         page = k_pool.shape[1]
         stripe = self.ctx.stripe_index()
         F = self.ctx.tp
@@ -216,7 +218,7 @@ class StripedPrefillBackend:
         v_pool = paged_append(v_pool, vf, slots)
         w = window if window is not None else self.window
         out = causal_attention(q, k, v, window=w)
-        return out, (k_pool, v_pool)
+        return out, (k_pool.reshape(stored), v_pool.reshape(stored))
 
     def append_ctx(self, state, vals, *, positions):
         """MLA prefill: write striped, return the in-line context."""

@@ -49,7 +49,7 @@ def global_states(model, geom, mode, batch_per_group, mesh, phase,
             st = dict(st)
             if kind[0] in ("gqa", "gqa_win", "mla"):
                 st["mixer"] = tuple(
-                    jax.ShapeDtypeStruct(geom.flat_shape(), s.dtype)
+                    jax.ShapeDtypeStruct(geom.pool_shape(), s.dtype)
                     for s in st["mixer"])
             new = {}
             for k, leaves in st.items():

@@ -31,11 +31,11 @@ def main():
         assert jax.tree.leaves(jax.tree.map(_ptrs, p)) == base_ptrs
     print("weights: zero-copy across merge modes 1<->2<->4 OK")
 
-    # KV pool: flat [G1, G2, nblk, elems] leaf, same story
+    # KV pool: [G1, G2, nblk, B_base, W] leaf, same story
     geom = PoolGeometry(cfg, plan, num_blocks=16, block_base=4)
     pool = jnp.zeros((plan.dp_engines, plan.engine_rows * plan.tp_base)
-                     + geom.flat_shape(), jnp.float32)
-    spec = P(("pod", "dp", "merge"), ("ed", "model"), None, None)
+                     + geom.pool_shape(), jnp.float32)
+    spec = P(("pod", "dp", "merge"), ("ed", "model"), None, None, None)
     a = jax.device_put(pool, NamedSharding(meshes[1], spec))
     ptrs = _ptrs(a)
     for m in (2, 4, 1):

@@ -10,6 +10,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from repro.configs import get_config
 from repro.core.engine import FlyingEngine
@@ -187,9 +188,9 @@ def _iter_eqns(jaxpr):
         for p in eqn.params.values():
             subs = p if isinstance(p, (tuple, list)) else (p,)
             for q in subs:
-                if isinstance(q, jax.core.ClosedJaxpr):
+                if isinstance(q, jex_core.ClosedJaxpr):
                     yield from _iter_eqns(q.jaxpr)
-                elif isinstance(q, jax.core.Jaxpr):
+                elif isinstance(q, jex_core.Jaxpr):
                     yield from _iter_eqns(q)
 
 

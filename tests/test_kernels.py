@@ -33,11 +33,17 @@ def test_paged_attention(B, H, KV, hd, page, nblk, MB, window, dtype):
     # impl="interpret" forces the Pallas kernel through the interpreter
     # (the auto dispatch picks the jnp ref on CPU — that would compare
     # the oracle against itself)
-    out = paged_attention(q, kp, vp, bt, cl, window=window,
+    out = paged_attention(q, _phys(kp), _phys(vp), bt, cl, window=window,
                           impl="interpret")
     ref = paged_attention_ref(q, kp, vp, bt, cl, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tols(dtype))
+
+
+def _phys(view):
+    """The stored pool layout [nblk, page, KV*hd] of a head-split-1 view
+    [nblk, page, KV, hd] (the same bytes, row-major)."""
+    return view.reshape(view.shape[0], view.shape[1], -1)
 
 
 def _disjoint_tables(k, B, MB, nblk):
@@ -74,14 +80,15 @@ def test_paged_attention_decode_fused_append(B, H, KV, hd, page, nblk, MB,
     slots = (bt[jnp.arange(B), (cl - 1) // page] * page
              + (cl - 1) % page).astype(jnp.int32)
     slots = slots.at[0].set(-1)  # parked row -> scratch, never read
-    out, ko, vo = paged_attention_decode(q, kn, vn, kp, vp, slots, bt, cl,
-                                         window=window, impl="interpret")
+    out, ko, vo = paged_attention_decode(q, kn, vn, _phys(kp), _phys(vp),
+                                         slots, bt, cl, window=window,
+                                         impl="interpret")
     kr, vr = paged_append_token_ref((kp, vp), (kn, vn), slots)
     ref = paged_attention_ref(q, kr, vr, bt, cl, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tols(dtype))
-    np.testing.assert_array_equal(np.asarray(ko), np.asarray(kr))
-    np.testing.assert_array_equal(np.asarray(vo), np.asarray(vr))
+    np.testing.assert_array_equal(np.asarray(ko), np.asarray(_phys(kr)))
+    np.testing.assert_array_equal(np.asarray(vo), np.asarray(_phys(vr)))
 
 
 @pytest.mark.parametrize("B,H,R,Rr,page,nblk,MB", [
@@ -134,9 +141,10 @@ def test_paged_append_chunk(B, T, KV, w, page, nblk, MB, dtype):
     slots = (bt[jnp.arange(B)[:, None], pos // page] * page
              + pos % page).astype(jnp.int32)
     slots = slots.at[0, -1].set(-1)  # parked row -> scratch, never read
-    (ko,) = paged_append_chunk_kernel((kp,), (kn,), slots, interpret=True)
+    (ko,) = paged_append_chunk_kernel((_phys(kp),), (kn,), slots,
+                                      interpret=True)
     (kr,) = paged_append_chunk_ref((kp,), (kn,), slots)
-    np.testing.assert_array_equal(np.asarray(ko), np.asarray(kr))
+    np.testing.assert_array_equal(np.asarray(ko), np.asarray(_phys(kr)))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -165,6 +173,7 @@ def test_paged_flash_prefill(B, T, H, KV, hd, page, nblk, MB, window,
     pos = prior[:, None] + jnp.arange(T)[None]
     slots = (bt[jnp.arange(B)[:, None], pos // page] * page
              + pos % page).astype(jnp.int32)
+    kp, vp = _phys(kp), _phys(vp)
     oi, ki, vi = paged_flash_prefill(q, kn, vn, kp, vp, slots, bt, prior,
                                      window=window, blk_q=8,
                                      impl="interpret")
@@ -174,6 +183,75 @@ def test_paged_flash_prefill(B, T, H, KV, hd, page, nblk, MB, window,
                                np.asarray(orf, np.float32), **tols(dtype))
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(krf))
     np.testing.assert_array_equal(np.asarray(vi), np.asarray(vrf))
+
+
+@pytest.mark.parametrize("op", ["decode", "decode_lse", "append_token",
+                                "append_chunk", "prefill", "prefill_lse"])
+@pytest.mark.parametrize("hs", [2, 4])
+def test_head_split_view_kernels(hs, op):
+    """A merge reads the physical pool [nblk, page, W] under head split
+    hs as [nblk, page*hs, W/(hs*hd), hd] (view token t*hs + j = lane
+    group j of row t). Every kernel indexes that view in place and must
+    match the oracles run on the explicitly reshaped view."""
+    from repro.kernels.flash_prefill.ops import (paged_flash_prefill,
+                                                 paged_prefill_sweep_with_lse)
+    from repro.kernels.paged_attention import ops
+    from repro.kernels.paged_attention.ref import pool_view
+    B, KV, rep, hd, page, nblk, MB, T = 3, 8, 2, 32, 4, 40, 3, 8
+    kvv, cap = KV // hs, page * hs
+    H = kvv * rep
+    ks = jax.random.split(key, 8)
+    kp = jax.random.normal(ks[0], (nblk, page, KV * hd))
+    vp = jax.random.normal(ks[1], (nblk, page, KV * hd))
+    bt = _disjoint_tables(ks[2], B, MB, nblk)
+    run = {}
+    for impl in ("interpret", "ref"):
+        if op.startswith("decode"):
+            q = jax.random.normal(ks[3], (B, H, hd))
+            cl = jnp.asarray([1, cap + 3, MB * cap], jnp.int32)
+            fn = ops.paged_attention_with_lse if op == "decode_lse" \
+                else ops.paged_attention
+            run[impl] = fn(q, kp, vp, bt, cl, hs=hs, impl=impl)
+        elif op.startswith("append"):
+            T_ = 1 if op == "append_token" else T
+            vals = jax.random.normal(ks[4], (B, T_, kvv, hd))
+            pos = jnp.asarray([0, 5, cap + 1], jnp.int32)[:, None] + \
+                jnp.arange(T_)[None]
+            slots = (bt[jnp.arange(B)[:, None], pos // cap] * cap
+                     + pos % cap).astype(jnp.int32)
+            slots = slots.at[0, -1].set(-1)
+            if op == "append_token":
+                run[impl] = ops.paged_append_token(
+                    (kp,), (vals[:, 0],), slots[:, 0], hs=hs, impl=impl)
+            else:
+                run[impl] = ops.paged_append_chunk((kp,), (vals,), slots,
+                                                   hs=hs, impl=impl)
+        else:
+            q = jax.random.normal(ks[5], (B, T, H, hd))
+            prior = jnp.asarray([0, 3, cap + 2], jnp.int32)
+            if op == "prefill_lse":
+                run[impl] = paged_prefill_sweep_with_lse(
+                    q, kp, vp, bt, prior, hs=hs, prior_only=True,
+                    impl=impl)
+            else:
+                kn = jax.random.normal(ks[6], (B, T, kvv, hd))
+                vn = jax.random.normal(ks[7], (B, T, kvv, hd))
+                pos = prior[:, None] + jnp.arange(T)[None]
+                slots = (bt[jnp.arange(B)[:, None], pos // cap] * cap
+                         + pos % cap).astype(jnp.int32)
+                run[impl] = paged_flash_prefill(
+                    q, kn, vn, kp, vp, slots, bt, prior, hs=hs, blk_q=8,
+                    impl=impl)
+    assert pool_view(kp, hs, hd).shape == (nblk, cap, kvv, hd)
+    for a, b in zip(jax.tree.leaves(run["interpret"]),
+                    jax.tree.leaves(run["ref"])):
+        a, b = np.asarray(a), np.asarray(b)
+        if op.startswith("append"):
+            np.testing.assert_array_equal(a, b)
+        else:
+            ok = b > -1e30     # lse of rows with nothing live: NEG_INF
+            np.testing.assert_allclose(np.where(ok, a, 0), np.where(ok, b, 0),
+                                       rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -1,7 +1,8 @@
 """KV Cache Adaptor property tests (paper §4.2 invariants)."""
 import numpy as np
 import pytest
-from hyp_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.core.kv_adaptor import KVCacheAdaptor, PoolGeometry

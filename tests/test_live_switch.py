@@ -158,7 +158,7 @@ def test_cross_tag_read_matches_dense_reference(impl):
     k_ctx = rng.normal(size=(L1 + L2, KV, hd)).astype(np.float32)
     v_ctx = rng.normal(size=(L1 + L2, KV, hd)).astype(np.float32)
 
-    # physical pools: flat [nb, bb*KV*hd] per rank
+    # per-rank pool bytes, [nb, bb*KV*hd] (filled through the views)
     flat = [np.zeros((nb, bb * KV * hd), np.float32) for _ in range(2)]
     flat_v = [np.zeros((nb, bb * KV * hd), np.float32) for _ in range(2)]
     # tag-1 segment: blocks 0-1 on rank 0 (owner engine), view
@@ -188,11 +188,10 @@ def test_cross_tag_read_matches_dense_reference(impl):
     for v_rank in range(2):
         partials = []
         for tag, ids, ln, own in segs:
-            cap = bb * tag
-            kvh = KV // tag
             Hq = H // tag
-            view_k = jnp.asarray(flat[v_rank]).reshape(nb, cap, kvh, hd)
-            view_v = jnp.asarray(flat_v[v_rank]).reshape(nb, cap, kvh, hd)
+            # the stored pool [nb, bb, KV*hd], read under head split tag
+            phys_k = jnp.asarray(flat[v_rank]).reshape(nb, bb, KV * hd)
+            phys_v = jnp.asarray(flat_v[v_rank]).reshape(nb, bb, KV * hd)
             ok = own <= v_rank < own + tag
             eff = jnp.asarray([ln if ok else 0], jnp.int32)
             v_old = int(np.clip(v_rank - own, 0, tag - 1))
@@ -200,7 +199,7 @@ def test_cross_tag_read_matches_dense_reference(impl):
             bt = np.zeros((B, len(ids)), np.int32)
             bt[0, :] = ids
             out_t, lse_t = pa_ops.paged_attention_with_lse(
-                q_sub, view_k, view_v, jnp.asarray(bt), eff,
+                q_sub, phys_k, phys_v, jnp.asarray(bt), eff, hs=tag,
                 softmax_scale=hd ** -0.5, impl=impl)
             partials.append(_seg_scatter(
                 out_t, lse_t, jnp.asarray([v_old]),

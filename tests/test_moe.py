@@ -3,7 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hyp_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.core.views import SINGLE

@@ -147,8 +147,9 @@ def test_cross_shard_lse_merge_matches_dense(impl):
     key = jax.random.key(0)
     kq, kk, kv_ = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, H, hd), jnp.float32)
-    k_pool = jax.random.normal(kk, (nb, page, KV, hd), jnp.float32)
-    v_pool = jax.random.normal(kv_, (nb, page, KV, hd), jnp.float32)
+    # physical pools [nb, page, KV*hd]
+    k_pool = jax.random.normal(kk, (nb, page, KV * hd), jnp.float32)
+    v_pool = jax.random.normal(kv_, (nb, page, KV * hd), jnp.float32)
     bt = jnp.tile(jnp.arange(nb, dtype=jnp.int32)[None], (B, 1))
     clen = jnp.full((B,), ctx, jnp.int32)
     full, _ = paged_attention_with_lse(q, k_pool, v_pool, bt, clen,

@@ -1,7 +1,8 @@
 """Cost-model sanity properties (the simulator is the benchmark
 substrate, so its monotonicities must hold)."""
 import pytest
-from hyp_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.core.modes import ParallelPlan

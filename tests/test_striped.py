@@ -5,7 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hyp_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models.striped import stripe_counts, stripe_write_slot
 

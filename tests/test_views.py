@@ -1,6 +1,7 @@
 """TPContext shard-math unit + property tests (host-side, no devices)."""
 import pytest
-from hyp_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.views import TPContext, pow2_shards, v2
 
