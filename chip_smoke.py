@@ -147,10 +147,9 @@ def compiled_step_texts(engine) -> dict:
     states = jax.tree.map(like, rt.states)
     out = {}
     for phase, batch in batches.items():
-        fn = engine.pool.runner(
-            rt.island, phase, sampled=True, donate=True, batch_bucket=B,
-            seq_bucket=T if phase == "prefill" else 1, mb_bucket=mb)
-        out[phase] = fn.lower(params, states, batch).compile().as_text()
+        out[phase] = engine.pool.precompile(
+            rt.island, phase, (params, states, batch), sampled=True,
+            donate=True).as_text()
     return out
 
 

@@ -20,9 +20,18 @@ concrete device slice resolving from the island-committed params and
 states at call time.
 
 At runtime a mode switch is an O(1) dict lookup (paper: "retrieved in
-O(1) time"); nothing is created on the critical path. ``stats`` records
-lookup vs. compile times — benchmarks/table2 reports the gap (the
-paper's 15 ms live vs. 146-292 s cold start).
+O(1) time"); nothing is created on the critical path. ``stats`` counts
+the step programs built (compiled, or loaded from the persistent cache)
+and their host seconds, on the serving path too: a runner call whose
+jitted function's cache grew across the call was a build. The cache's
+size is ``jax.jit``'s private ``_cache_size()``, read twice a launch
+with two clock reads; a JAX that drops it fails
+``test_a_runner_call_that_builds_counts_once`` on the CPU.
+
+Each step program is named for its phase and island shape
+(``fs_<phase>_n<engines>m<merge>``, ``s<sp>`` on SP islands): the XLA
+module of every run carries the name, and each call is an ``fs.launch``
+span (``core/tracing.py``).
 
 Hot-path contract (§Perf D):
   - ``runner(..., sampled=True)`` compiles the sampling-fused step:
@@ -46,6 +55,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 
 from repro.configs.base import ArchConfig
+from repro.core import tracing
 from repro.core.kv_adaptor import PoolGeometry
 from repro.core.modes import (FlyingMode, Island, ParallelPlan,
                               island_abstract_mesh, island_mesh,
@@ -76,13 +86,26 @@ def bucket_pow2(n: int, lo: int = 1) -> int:
     return b
 
 
+def _runner_key(island: Island, phase: str, sampled: bool, donate: bool,
+                bb, sb, mb, live) -> Tuple:
+    return (island.merge, phase, sampled, donate, bb, sb, mb,
+            island.n_engines, live, island.sp)
+
+
+def _program(name: str, key: Tuple) -> str:
+    """Short id of a runner key: ``fs_decode_n1m1/b16/s1/mb8``."""
+    bb, sb, mb, live = key[4], key[5], key[6], key[8]
+    return "/".join([name, f"b{bb}", f"s{sb}", f"mb{mb}"]
+                    + (["-".join(map(str, live))] if live else []))
+
+
 @dataclass
 class PoolStats:
+    """Step programs built: compiled, or loaded from the persistent
+    compilation cache (``precompile`` and serving-path calls alike)."""
     compiles: int = 0
-    compile_s: float = 0.0
-    lookups: int = 0
-    lookup_s: float = 0.0
-    misses: int = 0
+    compile_s: float = 0.0          # host seconds of the calls that built
+    last_compiled: str = ""         # program id of the newest build
 
 
 class CommunicatorPool:
@@ -107,6 +130,7 @@ class CommunicatorPool:
             m: mode_mesh(fm) for m, fm in self.modes.items()}
         self._island_meshes: Dict[Island, jax.sharding.Mesh] = {}
         self._runners: Dict[Tuple, Callable] = {}
+        self._jitted: Dict[Tuple, Callable] = {}
         self._compiled: Dict[Tuple, Any] = {}
         self.stats = PoolStats()
 
@@ -130,7 +154,9 @@ class CommunicatorPool:
                seq_bucket: Optional[int] = None,
                mb_bucket: Optional[int] = None,
                live: Optional[Tuple[int, ...]] = None) -> Callable:
-        """Jitted step fn for (island shape, phase, variant).
+        """Step fn for (island shape, phase, variant): a call of its
+        jitted step program, under an ``fs.launch`` span, that counts a
+        build in ``stats`` when the call compiled (or loaded) one.
 
         ``island`` is an ``Island`` (or a bare merge, meaning the
         degenerate whole-fleet island). The runner key is the island's
@@ -158,10 +184,30 @@ class CommunicatorPool:
         plain merge island of the same shape.
         """
         island = self._as_island(island)
-        sp = island.sp
-        key = (island.merge, phase, sampled, donate, batch_bucket,
-               seq_bucket, mb_bucket, island.n_engines, live, sp)
-        if key not in self._runners:
+        key = _runner_key(island, phase, sampled, donate, batch_bucket,
+                          seq_bucket, mb_bucket, live)
+        fn = self._runners.get(key)
+        if fn is None:
+            jitted = self._jit(island, phase, key)
+            program = _program(jitted.__name__, key)
+
+            def fn(params, states, batch):
+                with tracing.span("fs.launch", program=program):
+                    n = jitted._cache_size()
+                    t0 = time.perf_counter()
+                    out = jitted(params, states, batch)
+                    if jitted._cache_size() > n:
+                        self._built(program, time.perf_counter() - t0)
+                return out
+            self._runners[key] = fn
+        return fn
+
+    def _jit(self, island: Island, phase: str, key: Tuple):
+        """The jitted step program of a runner key, named
+        ``fs_<phase>_n<engines>m<merge>`` (``s<sp>`` on SP islands)."""
+        jitted = self._jitted.get(key)
+        if jitted is None:
+            sampled, donate, live, sp = key[2], key[3], key[8], key[9]
             if donate:
                 _quiet_unused_donation()
             run, _, _ = build_serve_step(
@@ -170,9 +216,21 @@ class CommunicatorPool:
                 chunked=(phase == "prefill" and self.chunked),
                 sample=self.sample if sampled else None, live=live, sp=sp,
                 mesh=island_abstract_mesh(self.plan, island.shape))
-            self._runners[key] = jax.jit(
-                run, donate_argnums=(1,) if donate else ())
-        return self._runners[key]
+            name = f"fs_{phase}_n{island.n_engines}m{island.merge}" \
+                + (f"s{sp}" if sp > 1 else "")
+
+            def step(params, states, batch):
+                with jax.named_scope(name):
+                    return run(params, states, batch)
+            step.__name__ = step.__qualname__ = name
+            jitted = self._jitted[key] = jax.jit(
+                step, donate_argnums=(1,) if donate else ())
+        return jitted
+
+    def _built(self, program: str, seconds: float) -> None:
+        self.stats.compiles += 1
+        self.stats.compile_s += seconds
+        self.stats.last_compiled = program
 
     # -- step 2: pre-initialization --------------------------------------
     def precompile(self, island, phase: str, abstract_args, *,
@@ -185,13 +243,11 @@ class CommunicatorPool:
         if key in self._compiled:
             return self._compiled[key]
         t0 = time.perf_counter()
-        runner = self.runner(island, phase, sampled=sampled, donate=donate,
-                             batch_bucket=key[4], seq_bucket=key[5],
-                             mb_bucket=key[6], live=live)
-        lowered = runner.lower(*abstract_args)
-        compiled = lowered.compile()
-        self.stats.compiles += 1
-        self.stats.compile_s += time.perf_counter() - t0
+        rkey = _runner_key(island, phase, sampled, donate, *key[4:7], live)
+        jitted = self._jit(island, phase, rkey)
+        compiled = jitted.lower(*abstract_args).compile()
+        self._built(_program(jitted.__name__, rkey),
+                    time.perf_counter() - t0)
         self._compiled[key] = compiled
         return compiled
 
@@ -199,15 +255,11 @@ class CommunicatorPool:
             allow_compile: bool = True, *, sampled: bool = False,
             donate: bool = False) -> Any:
         """O(1) retrieval on the serving critical path."""
-        t0 = time.perf_counter()
         island = self._as_island(island)
         key = self._key(island, phase, abstract_args, sampled, donate)
         hit = self._compiled.get(key)
-        self.stats.lookups += 1
-        self.stats.lookup_s += time.perf_counter() - t0
         if hit is not None:
             return hit
-        self.stats.misses += 1
         if not allow_compile:
             raise KeyError(f"executable {key} not pre-initialized")
         return self.precompile(island, phase, abstract_args,

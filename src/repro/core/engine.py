@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core import tracing
 from repro.core.communicator_pool import CommunicatorPool, bucket_pow2
 from repro.core.faults import TransitionFault
 from repro.core.kv_adaptor import (KVCacheAdaptor, PoolGeometry,
@@ -352,65 +353,68 @@ class FlyingEngine:
         assert layout.plan == self.plan
         if layout == self.layout:
             return 0.0
-        inj = self.injector
-        if inj is not None:
-            s = inj.take_rebind_fault()
-            if s is not None:
-                # scripted failure BEFORE any state moves: the engine
-                # stays bound to the old layout, which is exactly what
-                # the scheduler's rollback assumes
-                raise TransitionFault(
-                    f"scripted rebind failure (tick {inj.tick})")
-        t0 = time.perf_counter()
-        new_set = set(layout.islands)
-        changed = [rt for rt in self.islands if rt.island not in new_set]
-        changed_engs = {e for rt in changed for e in rt.island.engines()}
-        dead: set = set()
-        if inj is not None:
-            s = inj.take_drain_corrupt(changed_engs)
-            if s is not None:
-                bad = (set(s.engines) & changed_engs) or set(s.engines)
-                # the corruption IS the loss of in-flight tokens on the
-                # named islands; layout state is untouched, so rollback
-                # plus recovery (re-prefill from harvested tokens) is
-                # still well-defined
+        with tracing.span("fs.rebind", frm=self.layout.describe,
+                          to=layout.describe):
+            inj = self.injector
+            if inj is not None:
+                s = inj.take_rebind_fault()
+                if s is not None:
+                    # scripted failure BEFORE any state moves: the engine
+                    # stays bound to the old layout, which is exactly what
+                    # the scheduler's rollback assumes
+                    raise TransitionFault(
+                        f"scripted rebind failure (tick {inj.tick})")
+            t0 = time.perf_counter()
+            new_set = set(layout.islands)
+            changed = [rt for rt in self.islands if rt.island not in new_set]
+            changed_engs = {e for rt in changed for e in rt.island.engines()}
+            dead: set = set()
+            if inj is not None:
+                s = inj.take_drain_corrupt(changed_engs)
+                if s is not None:
+                    bad = (set(s.engines) & changed_engs) or set(s.engines)
+                    # the corruption IS the loss of in-flight tokens on the
+                    # named islands; layout state is untouched, so rollback
+                    # plus recovery (re-prefill from harvested tokens) is
+                    # still well-defined
+                    for rt in changed:
+                        if set(rt.island.engines()) & bad:
+                            self._discard_island(rt)
+                    raise TransitionFault(
+                        "drain corrupted at the rebind safe point",
+                        engines=bad)
+                dead = set(inj.dead_engines())
+            with tracing.span("fs.drain", islands=len(changed)):
                 for rt in changed:
-                    if set(rt.island.engines()) & bad:
+                    if set(rt.island.engines()) & dead:
+                        # a dead engine cannot answer the drain transfer: its
+                        # island's unharvested tokens are lost (recovery
+                        # folds whatever reached the host buffer earlier)
                         self._discard_island(rt)
-                raise TransitionFault(
-                    "drain corrupted at the rebind safe point",
-                    engines=bad)
-            dead = set(inj.dead_engines())
-        for rt in changed:
-            if set(rt.island.engines()) & dead:
-                # a dead engine cannot answer the drain transfer: its
-                # island's unharvested tokens are lost (recovery folds
-                # whatever reached the host buffer earlier)
-                self._discard_island(rt)
-            else:
-                self._drain_island(rt)
-        # recurrent states are per-request and batch-dense, and enc-dec
-        # cross caches carry merge-dependent per-device shapes: reshaped
-        # islands rebuild those (the documented exception to zero-copy;
-        # only batch-invariant flat paged pools re-assemble)
-        rebuild = self.cfg.family in ("ssm", "hybrid") \
-            or self.cfg.enc_dec is not None
-        keep = self._rt_of
-        self.islands = [
-            keep.get(isl) or self._make_rt(
-                isl, sources=None if rebuild else changed)
-            for isl in layout.islands]
-        self._rt_of = {rt.island: rt for rt in self.islands}
-        self.layout = layout
-        bind_fleet(self.adaptors, layout)
-        # staging buffers are keyed per island: drop dead islands' so
-        # layout churn doesn't grow host memory without bound
-        live = set(layout.islands)
-        self._host_bufs = {k: v for k, v in self._host_bufs.items()
-                           if k[1] in live}
-        dt = time.perf_counter() - t0
-        self.switch_log.append(dt)
-        return dt
+                    else:
+                        self._drain_island(rt)
+            # recurrent states are per-request and batch-dense, and enc-dec
+            # cross caches carry merge-dependent per-device shapes: reshaped
+            # islands rebuild those (the documented exception to zero-copy;
+            # only batch-invariant flat paged pools re-assemble)
+            rebuild = self.cfg.family in ("ssm", "hybrid") \
+                or self.cfg.enc_dec is not None
+            keep = self._rt_of
+            self.islands = [
+                keep.get(isl) or self._make_rt(
+                    isl, sources=None if rebuild else changed)
+                for isl in layout.islands]
+            self._rt_of = {rt.island: rt for rt in self.islands}
+            self.layout = layout
+            bind_fleet(self.adaptors, layout)
+            # staging buffers are keyed per island: drop dead islands' so
+            # layout churn doesn't grow host memory without bound
+            live = set(layout.islands)
+            self._host_bufs = {k: v for k, v in self._host_bufs.items()
+                               if k[1] in live}
+            dt = time.perf_counter() - t0
+            self.switch_log.append(dt)
+            return dt
 
     def switch(self, old: int, new: int) -> float:
         """Seed-era uniform transition: rebind to the uniform layout of
@@ -550,13 +554,15 @@ class FlyingEngine:
         if self.window == 0:
             # depth-0 window = fully synchronous dispatch (tokens still
             # stay on device; only completion is awaited)
-            toks_dev.block_until_ready()
+            with tracing.span("fs.wait"):
+                toks_dev.block_until_ready()
             self.sync_stats.window_waits += 1
             rt.stats.window_waits += 1
         elif len(rt.pending) > self.window:
             # bounded in-flight window: wait for the step that left the
             # window to COMPLETE (no transfer — tokens stay on device)
-            rt.pending[-self.window - 1][0].block_until_ready()
+            with tracing.span("fs.wait"):
+                rt.pending[-self.window - 1][0].block_until_ready()
             self.sync_stats.window_waits += 1
             rt.stats.window_waits += 1
         if len(rt.pending) >= self.harvest_limit:
@@ -566,14 +572,16 @@ class FlyingEngine:
         """Move one island's pending device token arrays into the host
         token buffer (one batched [B] transfer per step harvested, never
         per-token)."""
-        for toks_dev, row_reqs in rt.pending:
-            arr = np.asarray(toks_dev)
-            self.sync_stats.d2h_batched += 1
-            rt.stats.d2h_batched += 1
-            for row, rid in row_reqs:
-                if rid in self._retired:
-                    continue    # aborted mid-flight: drop, don't buffer
-                self._token_buf.setdefault(rid, []).append(int(arr[row]))
+        with tracing.span("fs.harvest", steps=len(rt.pending)):
+            for toks_dev, row_reqs in rt.pending:
+                arr = np.asarray(toks_dev)
+                self.sync_stats.d2h_batched += 1
+                rt.stats.d2h_batched += 1
+                for row, rid in row_reqs:
+                    if rid in self._retired:
+                        continue    # aborted mid-flight: drop, don't buffer
+                    self._token_buf.setdefault(rid, []).append(
+                        int(arr[row]))
         rt.pending.clear()
         rt.last_tok.clear()
 
@@ -611,8 +619,9 @@ class FlyingEngine:
 
     def drain(self) -> None:
         """Fleet-wide safe point (scheduler end-of-run, host readout)."""
-        for rt in self.islands:
-            self._drain_island(rt)
+        with tracing.span("fs.drain", islands=len(self.islands)):
+            for rt in self.islands:
+                self._drain_island(rt)
         # no pending ring references any retired row anymore
         self._retired.clear()
 
@@ -832,35 +841,63 @@ class FlyingEngine:
                 batch[k] = self._h2d(v)
         return batch, rows, final, T, mb, live
 
+    def _step_span(self, phase: str, rt: _IslandRT,
+                   pre: Sequence[Request] = (), dec: Sequence[Request] = ()):
+        """The ``fs.step`` span of one launch, read before it runs: its
+        island, each decode row's (lead engine, context incl. the new
+        token) and each prefill chunk's (lead engine, prior tokens,
+        chunk tokens, final)."""
+        def entry(r):
+            return self.adaptors[r.engine_group].table[r.req_id]
+
+        def chunks():
+            out = []
+            for r in pre:
+                end = min(int(entry(r).length), int(r.prompt_len))
+                prior = min(max(int(r.prefilled), 0), end)
+                out.append((r.engine_group, prior, end - prior,
+                            end >= r.prompt_len))
+            return tracing.rows(out)
+
+        isl = rt.island
+        return tracing.span(
+            "fs.step", phase=phase, step=self.sync_stats.steps,
+            island=lambda: f"{isl.start}/{isl.n_engines}/{isl.merge}",
+            rids=lambda: ":".join(r.req_id for r in (*pre, *dec)),
+            dec=lambda: tracing.rows((r.engine_group, entry(r).length)
+                                     for r in dec),
+            pre=chunks)
+
     def prefill(self, reqs: Sequence[Request], island: Union[Island, int],
                 chunk_tokens: int) -> float:
         rt = self._resolve(island)
         self._fault_gate(rt.island)
         t0 = time.perf_counter()
-        B = rt.B
-        batch, rows, final, T, mb, live = self._stage_prefill(rt, reqs)
-        seeds = self._sample_seeds(B, reqs, rows, "prefill")
-        if seeds is not None:
-            batch["sample_seeds"] = seeds
-        runner = self.pool.runner(
-            rt.island, "prefill", sampled=self.fused, donate=self.donate,
-            batch_bucket=B, seq_bucket=T, mb_bucket=mb, live=live)
-        self.sync_stats.steps += 1
-        rt.stats.steps += 1
-        if self.fused:
-            toks_dev, rt.states = runner(rt.params, rt.states, batch)
-            # only FINAL chunks emit a token; mid-prompt chunks leave the
-            # device token ring (and its decode feed-back key) untouched
-            row_reqs = tuple((int(row), r.req_id)
-                             for row, r, f in zip(rows, reqs, final) if f)
-            if row_reqs:
-                # prefill membership never matches a decode key: the next
-                # decode gathers these first tokens on device by row map
-                self._note_tokens(rt, None, toks_dev, row_reqs)
-        else:
-            logits, rt.states = runner(rt.params, rt.states, batch)
-            self._host_sample(rt, [(r, row) for r, row, f in
-                                   zip(reqs, rows, final) if f], logits)
+        with self._step_span("prefill", rt, pre=reqs):
+            B = rt.B
+            batch, rows, final, T, mb, live = self._stage_prefill(rt, reqs)
+            seeds = self._sample_seeds(B, reqs, rows, "prefill")
+            if seeds is not None:
+                batch["sample_seeds"] = seeds
+            runner = self.pool.runner(
+                rt.island, "prefill", sampled=self.fused, donate=self.donate,
+                batch_bucket=B, seq_bucket=T, mb_bucket=mb, live=live)
+            self.sync_stats.steps += 1
+            rt.stats.steps += 1
+            if self.fused:
+                toks_dev, rt.states = runner(rt.params, rt.states, batch)
+                # only FINAL chunks emit a token; mid-prompt chunks leave the
+                # device token ring (and its decode feed-back key) untouched
+                row_reqs = tuple((int(row), r.req_id)
+                                 for row, r, f in zip(rows, reqs, final) if f)
+                if row_reqs:
+                    # prefill membership never matches a decode key: the next
+                    # decode gathers these first tokens on device by row map
+                    self._note_tokens(rt, None, toks_dev, row_reqs)
+            else:
+                logits, rt.states = runner(rt.params, rt.states, batch)
+                self._host_sample(rt, [(r, row) for r, row, f in
+                                       zip(reqs, rows, final) if f], logits)
         return time.perf_counter() - t0
 
     # ------------------------------------------------------------------
@@ -930,59 +967,60 @@ class FlyingEngine:
             return (self.prefill(prefills, island, chunk_tokens)
                     + self.decode(decodes, island))
         t0 = time.perf_counter()
-        B = rt.B
-        cap = self.geom.capacity(isl.merge)
-        # shared mb bucket: the widest need of either phase, so both
-        # block tables stage (and compile) at one width per runner key
-        pre_blocks = max(len(self.adaptors[r.engine_group]
-                             .table[r.req_id].block_ids) for r in prefills)
-        dec_len = max(self.adaptors[r.engine_group].table[r.req_id].length
-                      for r in decodes)
-        mb = max(self._mb_bucket(pre_blocks),
-                 self._mb_bucket(-(-int(dec_len) // cap)))
-        pbatch, prows, final, T, mb, _ = self._stage_prefill(rt, prefills,
-                                                             mb_min=mb)
-        c = self._decode_cache(rt, decodes, mb_min=mb)
-        bufs, drows = c.bufs, c.rows
-        tokens = self._stage_decode(rt, decodes, c)
-        # on-device routing for rows promoted out of THIS step's prefill:
-        # group-local prefill row index (both rows live on the same
-        # engine-group shard)
-        bpg = self.bpe * isl.merge
-        src = np.full((B,), -1, np.int32)
-        p_row_of = {r.req_id: int(row)
-                    for r, row, f in zip(prefills, prows, final) if f}
-        for r, drow in zip(decodes, drows):
-            pr = p_row_of.get(r.req_id)
-            if pr is not None:
-                src[drow] = pr % bpg
-        batch = {"p_" + k: v for k, v in pbatch.items()}
-        batch.update({
-            "d_tokens": tokens,
-            "d_positions": self._h2d(bufs["pos"]),
-            "d_slots": self._h2d(bufs["slots"]),
-            "d_block_table": self._h2d(bufs["btab"]),
-            "d_context_len": self._h2d(bufs["ctxl"]),
-            "d_src_rows": jnp.asarray(src),
-        })
-        # two seed draws mirror the sequential two-launch assignment, so
-        # stochastic sampling stays token-identical across the fusion
-        p_seeds = self._sample_seeds(B, prefills, prows, "prefill")
-        d_seeds = self._sample_seeds(B, decodes, drows, "decode")
-        if p_seeds is not None:
-            batch["p_sample_seeds"] = p_seeds
-            batch["d_sample_seeds"] = d_seeds
-        runner = self.pool.runner(
-            rt.island, "mixed", sampled=True, donate=self.donate,
-            batch_bucket=B, seq_bucket=T, mb_bucket=mb)
-        self.sync_stats.steps += 1  # ONE launch for the island's tick
-        rt.stats.steps += 1
-        (p_toks, d_toks), rt.states = runner(rt.params, rt.states, batch)
-        prow_reqs = tuple((int(row), r.req_id)
-                          for row, r, f in zip(prows, prefills, final) if f)
-        if prow_reqs:
-            self._note_tokens(rt, None, p_toks, prow_reqs)
-        self._note_tokens(rt, c.key, d_toks, c.row_reqs)
+        with self._step_span("mixed", rt, pre=prefills, dec=decodes):
+            B = rt.B
+            cap = self.geom.capacity(isl.merge)
+            # shared mb bucket: the widest need of either phase, so both
+            # block tables stage (and compile) at one width per runner key
+            pre_blocks = max(len(self.adaptors[r.engine_group]
+                                 .table[r.req_id].block_ids) for r in prefills)
+            dec_len = max(self.adaptors[r.engine_group].table[r.req_id].length
+                          for r in decodes)
+            mb = max(self._mb_bucket(pre_blocks),
+                     self._mb_bucket(-(-int(dec_len) // cap)))
+            pbatch, prows, final, T, mb, _ = self._stage_prefill(rt, prefills,
+                                                                 mb_min=mb)
+            c = self._decode_cache(rt, decodes, mb_min=mb)
+            bufs, drows = c.bufs, c.rows
+            tokens = self._stage_decode(rt, decodes, c)
+            # on-device routing for rows promoted out of THIS step's prefill:
+            # group-local prefill row index (both rows live on the same
+            # engine-group shard)
+            bpg = self.bpe * isl.merge
+            src = np.full((B,), -1, np.int32)
+            p_row_of = {r.req_id: int(row)
+                        for r, row, f in zip(prefills, prows, final) if f}
+            for r, drow in zip(decodes, drows):
+                pr = p_row_of.get(r.req_id)
+                if pr is not None:
+                    src[drow] = pr % bpg
+            batch = {"p_" + k: v for k, v in pbatch.items()}
+            batch.update({
+                "d_tokens": tokens,
+                "d_positions": self._h2d(bufs["pos"]),
+                "d_slots": self._h2d(bufs["slots"]),
+                "d_block_table": self._h2d(bufs["btab"]),
+                "d_context_len": self._h2d(bufs["ctxl"]),
+                "d_src_rows": jnp.asarray(src),
+            })
+            # two seed draws mirror the sequential two-launch assignment, so
+            # stochastic sampling stays token-identical across the fusion
+            p_seeds = self._sample_seeds(B, prefills, prows, "prefill")
+            d_seeds = self._sample_seeds(B, decodes, drows, "decode")
+            if p_seeds is not None:
+                batch["p_sample_seeds"] = p_seeds
+                batch["d_sample_seeds"] = d_seeds
+            runner = self.pool.runner(
+                rt.island, "mixed", sampled=True, donate=self.donate,
+                batch_bucket=B, seq_bucket=T, mb_bucket=mb)
+            self.sync_stats.steps += 1  # ONE launch for the island's tick
+            rt.stats.steps += 1
+            (p_toks, d_toks), rt.states = runner(rt.params, rt.states, batch)
+            prow_reqs = tuple((int(row), r.req_id)
+                              for row, r, f in zip(prows, prefills, final) if f)
+            if prow_reqs:
+                self._note_tokens(rt, None, p_toks, prow_reqs)
+            self._note_tokens(rt, c.key, d_toks, c.row_reqs)
         return time.perf_counter() - t0
 
     # ------------------------------------------------------------------
@@ -1320,38 +1358,39 @@ class FlyingEngine:
         rt = self._resolve(island)
         self._fault_gate(rt.island)
         t0 = time.perf_counter()
-        B = rt.B
-        c = self._decode_cache(rt, reqs)
-        bufs = c.bufs
-        tokens = self._stage_decode(rt, reqs, c)
-        batch = {
-            "tokens": tokens,
-            "positions": self._h2d(bufs["pos"]),
-            "slots": self._h2d(bufs["slots"]),
-        }
-        if c.live is None:
-            batch["block_table"] = self._h2d(bufs["btab"])
-            batch["context_len"] = self._h2d(bufs["ctxl"])
-        else:
-            # no total context length: the live program masks entirely
-            # from the per-lane segment counts
-            for k in bufs:
-                if k.startswith("lt") or k == "write_own":
-                    batch[k] = self._h2d(bufs[k])
-        seeds = self._sample_seeds(B, reqs, c.rows, "decode")
-        if seeds is not None:
-            batch["sample_seeds"] = seeds
-        runner = self.pool.runner(
-            rt.island, "decode", sampled=self.fused, donate=self.donate,
-            batch_bucket=B, seq_bucket=1, mb_bucket=c.mb, live=c.live)
-        self.sync_stats.steps += 1
-        rt.stats.steps += 1
-        if self.fused:
-            toks_dev, rt.states = runner(rt.params, rt.states, batch)
-            self._note_tokens(rt, c.key, toks_dev, c.row_reqs)
-        else:
-            logits, rt.states = runner(rt.params, rt.states, batch)
-            self._host_sample(rt, list(zip(reqs, c.rows)), logits)
+        with self._step_span("decode", rt, dec=reqs):
+            B = rt.B
+            c = self._decode_cache(rt, reqs)
+            bufs = c.bufs
+            tokens = self._stage_decode(rt, reqs, c)
+            batch = {
+                "tokens": tokens,
+                "positions": self._h2d(bufs["pos"]),
+                "slots": self._h2d(bufs["slots"]),
+            }
+            if c.live is None:
+                batch["block_table"] = self._h2d(bufs["btab"])
+                batch["context_len"] = self._h2d(bufs["ctxl"])
+            else:
+                # no total context length: the live program masks entirely
+                # from the per-lane segment counts
+                for k in bufs:
+                    if k.startswith("lt") or k == "write_own":
+                        batch[k] = self._h2d(bufs[k])
+            seeds = self._sample_seeds(B, reqs, c.rows, "decode")
+            if seeds is not None:
+                batch["sample_seeds"] = seeds
+            runner = self.pool.runner(
+                rt.island, "decode", sampled=self.fused, donate=self.donate,
+                batch_bucket=B, seq_bucket=1, mb_bucket=c.mb, live=c.live)
+            self.sync_stats.steps += 1
+            rt.stats.steps += 1
+            if self.fused:
+                toks_dev, rt.states = runner(rt.params, rt.states, batch)
+                self._note_tokens(rt, c.key, toks_dev, c.row_reqs)
+            else:
+                logits, rt.states = runner(rt.params, rt.states, batch)
+                self._host_sample(rt, list(zip(reqs, c.rows)), logits)
         return time.perf_counter() - t0
 
     def _host_sample(self, rt: _IslandRT, req_rows, logits) -> None:

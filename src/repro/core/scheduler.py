@@ -51,6 +51,7 @@ import json
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Protocol, Sequence, Tuple, Union
 
+from repro.core import tracing
 from repro.core.faults import EngineFault, TransitionFault
 from repro.core.kv_adaptor import (KVCacheAdaptor, PoolGeometry,
                                    PrefixCache, bind_fleet)
@@ -330,6 +331,11 @@ class DynamicScheduler:
     def groups(self) -> int:
         return self.layout.n_groups
 
+    @property
+    def tick(self) -> int:
+        """Index of the newest ``step`` (-1 before the first)."""
+        return self._tick
+
     def _adaptor(self, lead_engine: int) -> KVCacheAdaptor:
         """Requests record their ABSOLUTE lead engine id (stable across
         rebinds); merged groups share the lead engine's table."""
@@ -509,10 +515,11 @@ class DynamicScheduler:
         # ③ Mode Determination (policy layer; Flag_SetTP / Flag_ResetTP)
         switched = False
         if self.cfg.fixed_merge is None and self.policy is not None:
-            target = self._sanitize(
-                self._as_layout(self.policy.decide(self)))
-            if target != self.layout:
-                switched = self._transition(target)
+            with tracing.span("fs.policy", layout=self.layout.describe):
+                target = self._sanitize(
+                    self._as_layout(self.policy.decide(self)))
+                if target != self.layout:
+                    switched = self._transition(target)
 
         # ④/⑥ KV parameterization + execution
         progressed = self._execute_one_step()

@@ -84,6 +84,7 @@ def flash_prefill_kernel(q, k, v, *, window: Optional[int] = None,
                              window=window)
     return pl.pallas_call(
         kern,
+        name="flash_prefill",
         grid=(B, H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, blk_q, hd), lambda b, h, i, j: (b, h, i, 0)),
@@ -247,6 +248,7 @@ def paged_flash_prefill_kernel(q, k_pool, v_pool, block_table, prior_len, *,
                              lambda b, i, j, t, p: (t[b, j], 0, 0))
     out, lse = pl.pallas_call(
         kern,
+        name="paged_flash_prefill",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_table, prior_len
             grid=(B, n_q, MB),

@@ -145,6 +145,7 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, context_len, *,
     row = lambda b, j, t, c: (b, 0, 0)  # noqa: E731
     out, lse = pl.pallas_call(
         kern,
+        name="paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # block_table, context_len
             grid=(B, MB),
@@ -219,7 +220,7 @@ def _append_kernel(blk_ref, off_ref, *refs, n: int, T: int, hs: int,
             o_ref[0] = jnp.where(mask, v_, p_ref[0])
 
 
-def _append(pools, vals, slots, *, hs: int, interpret: bool):
+def _append(pools, vals, slots, *, hs: int, interpret: bool, name: str):
     """pools: tuple of physical [nblk, page, W]; vals: matching tuple of
     [B, T, W // hs] view-frame rows; slots [B, T] int32 view slots
     (block * page*hs + offset; negative => parked to the reserved
@@ -241,6 +242,7 @@ def _append(pools, vals, slots, *, hs: int, interpret: bool):
                              lambda b, t, bl, of: (bl[b * T + t], 0, 0))
     outs = pl.pallas_call(
         functools.partial(_append_kernel, n=n, T=T, hs=hs, wv=wv),
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # blk, off
             grid=(B, T),
@@ -269,7 +271,8 @@ def paged_append_token_kernel(pools, vals, slots, *, hs: int = 1,
     and parked rows all target the don't-care scratch slot."""
     B = slots.shape[0]
     return _append(pools, [v.reshape(B, 1, -1) for v in vals],
-                   slots.reshape(B, 1), hs=hs, interpret=interpret)
+                   slots.reshape(B, 1), hs=hs, interpret=interpret,
+                   name="paged_append_token")
 
 
 def paged_append_chunk_kernel(pools, vals, slots, *, hs: int = 1,
@@ -283,4 +286,4 @@ def paged_append_chunk_kernel(pools, vals, slots, *, hs: int = 1,
     O(pool)."""
     B, T = slots.shape
     return _append(pools, [v.reshape(B, T, -1) for v in vals], slots,
-                   hs=hs, interpret=interpret)
+                   hs=hs, interpret=interpret, name="paged_append_chunk")

@@ -61,6 +61,7 @@ def rglru_scan_kernel(a, g, *, blk_t: int = 128, blk_c: int = 128,
     kern = functools.partial(_kernel, blk_t=blk_t, n_t=n_t)
     y, hT = pl.pallas_call(
         kern,
+        name="rglru_scan",
         grid=(B, C // blk_c, n_t),
         in_specs=[
             pl.BlockSpec((1, blk_t, blk_c), lambda b, c, t: (b, t, c)),

@@ -75,6 +75,7 @@ def ssd_scan_kernel(x, dt, A, B, C, *, chunk: int = 128,
     kern = functools.partial(_kernel, chunk=chunk, n_chunks=nc)
     y, hT = pl.pallas_call(
         kern,
+        name="ssd_scan",
         grid=(Bs, H, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, 1, hd), lambda b, h, c: (b, c, h, 0)),

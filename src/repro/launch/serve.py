@@ -73,6 +73,7 @@ class ServeConfig:
     stream_buf: int = 256                    # per-stream token buffer
     wall_dilation: float = 1.0               # virtual s per wall s
     metrics_window: float = 60.0             # rolling /metrics window
+    profile_port: int = 0                    # profiler server; 0 = off
 
     _CHOICES = {"strategy": ("hard", "soft", "sequential", "live"),
                 "switch": ("flying", "restart", "none"),
@@ -428,6 +429,12 @@ def main(argv=None):
     if cfg.real:
         from repro.launch.compile_cache import enable_compile_cache
         enable_compile_cache()
+    if cfg.profile_port:
+        # a live server a profiler can capture host spans and device
+        # activity from (TensorBoard's or XProf's "capture profile"); the
+        # program's spans are on while a capture records
+        import jax
+        jax.profiler.start_server(cfg.profile_port)
     if cfg.serve:
         serve_http(cfg)
     else:

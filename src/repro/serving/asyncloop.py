@@ -47,6 +47,7 @@ from __future__ import annotations
 import asyncio
 from typing import Dict, List, Optional, Tuple
 
+from repro.core import tracing
 from repro.core.task_pool import TERMINAL_STATES, Request
 from repro.serving.frontdoor import FrontDoor
 from repro.serving.metrics import RollingTierMetrics
@@ -298,6 +299,10 @@ class AsyncServeLoop:
         """Emit newly generated tokens into every live stream; abort
         slow consumers whose bounded queue is full; finalize terminal
         requests. O(live streams) per tick."""
+        with tracing.span("fs.pump", streams=len(self.streams)):
+            self._pump_streams()
+
+    def _pump_streams(self) -> None:
         now = self.door.sched.now
         by_tier: Dict[str, int] = {}
         for rid, st in list(self.streams.items()):
@@ -352,4 +357,16 @@ class AsyncServeLoop:
         stats = getattr(pol, "stats", None)
         if stats:
             out["forecast"] = dict(stats)
+        eng = sched.backend
+        s = getattr(eng, "sync_stats", None)
+        if s is not None:
+            # the real engine: host crossings, and the step programs
+            # built so far (which one rebuilt last)
+            ps = eng.pool.stats
+            out["engine"] = {
+                "steps": s.steps, "drains": s.drains,
+                "window_waits": s.window_waits,
+                "d2h_batched": s.d2h_batched, "compiles": ps.compiles,
+                "compile_s": ps.compile_s,
+                "last_compiled": ps.last_compiled}
         return out

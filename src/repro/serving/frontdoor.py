@@ -37,6 +37,7 @@ import json
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+from repro.core import tracing
 from repro.core.modes import Island
 from repro.core.scheduler import DynamicScheduler, SchedulerWedged
 from repro.core.task_pool import (PRIORITY_HIGH, PRIORITY_NORMAL,
@@ -406,11 +407,15 @@ class FrontDoor:
         so tokens produced THIS tick are judged against their deadlines
         before the next tick's admissions. Returns whether the
         scheduler made progress."""
-        self._observe_arrivals()
-        self._sweep()
-        self._admit()
-        progressed = self.sched.step()
-        self._sweep()
+        sched = self.sched
+        with tracing.span("fs.tick", tick=lambda: sched.tick,
+                          running=lambda: len(sched.running),
+                          queued=lambda: len(self._queue)):
+            self._observe_arrivals()
+            self._sweep()
+            self._admit()
+            progressed = sched.step()
+            self._sweep()
         return progressed
 
     def idle_advance(self) -> bool:

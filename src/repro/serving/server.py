@@ -33,6 +33,7 @@ import asyncio
 import json
 from typing import Dict, Optional, Tuple
 
+from repro.core import tracing
 from repro.core.task_pool import Request
 from repro.serving.asyncloop import AsyncServeLoop, TokenStream
 
@@ -238,16 +239,17 @@ class ServeHTTP:
                     self.loop.abort(req.req_id)
                     break
                 _, idx, tok, _t = ev
-                delta = {"index": 0, "finish_reason": None}
-                if chat:
-                    delta["delta"] = {"content": detok(tok)}
-                else:
-                    delta["text"] = detok(tok)
-                chunk = {"id": req.req_id, "object": obj,
-                         "choices": [delta], "token": tok,
-                         "token_index": idx}
-                writer.write(b"data: "
-                             + json.dumps(chunk).encode() + b"\n\n")
+                with tracing.span("fs.http.write"):
+                    delta = {"index": 0, "finish_reason": None}
+                    if chat:
+                        delta["delta"] = {"content": detok(tok)}
+                    else:
+                        delta["text"] = detok(tok)
+                    chunk = {"id": req.req_id, "object": obj,
+                             "choices": [delta], "token": tok,
+                             "token_index": idx}
+                    writer.write(b"data: "
+                                 + json.dumps(chunk).encode() + b"\n\n")
                 await writer.drain()
             else:
                 finish = "stop" if st.final_state == "done" \
@@ -256,8 +258,9 @@ class ServeHTTP:
                         "choices": [{"index": 0,
                                      "finish_reason": finish}],
                         "tier": req.tier}
-                writer.write(b"data: " + json.dumps(tail).encode()
-                             + b"\n\ndata: [DONE]\n\n")
+                with tracing.span("fs.http.write"):
+                    writer.write(b"data: " + json.dumps(tail).encode()
+                                 + b"\n\ndata: [DONE]\n\n")
                 await writer.drain()
         except (ConnectionResetError, BrokenPipeError, OSError):
             self.loop.abort(req.req_id)

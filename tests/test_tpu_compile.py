@@ -6,10 +6,14 @@ shapes against the (8, 128) tiling, in-kernel reshapes and scoped VMEM.
 Each kernel here is compiled with ``interpret=False`` against a
 described (not attached) v5e chip at stablelm-1.6b widths — 32 heads of
 64, KV blocks of 16 tokens, a 16-request decode batch, 256-token prefill
-chunks — and must contain its ``tpu_custom_call``. ``hs`` is the head
-split a merged (TP) mode reads the pool with.
+chunks — and must contain its ``tpu_custom_call``, named by the
+kernel's ``name`` (the instruction a chip trace shows). ``hs`` is the
+head split a merged (TP) mode reads the pool with. A decode step program
+compiled the same way carries its phase and island shape as its module
+name.
 """
 import os
+import re
 
 import pytest
 
@@ -45,10 +49,11 @@ def chip(topo):
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _compile(fn, chip, *shapes):
+def _compile(fn, chip, name, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name
 
 
 def _pool():
@@ -65,7 +70,7 @@ def test_paged_attention_kernel(chip, hs, return_lse):
     from repro.kernels.paged_attention.kernel import paged_attention_kernel
     dt = jnp.float32 if return_lse else jnp.bfloat16
     _compile(lambda q, k, v, t, c: paged_attention_kernel(
-        q, k, v, t, c, hs=hs, return_lse=return_lse), chip,
+        q, k, v, t, c, hs=hs, return_lse=return_lse), chip, "paged_decode",
         ((B, H // hs, HD), dt), _pool(), _pool(), _i32(B, MB), _i32(B))
 
 
@@ -75,7 +80,7 @@ def test_paged_append_token_kernel(chip, hs):
         paged_append_token_kernel
     new = ((B, KV // hs, HD), jnp.bfloat16)
     _compile(lambda k, v, kn, vn, s: paged_append_token_kernel(
-        (k, v), (kn, vn), s, hs=hs), chip,
+        (k, v), (kn, vn), s, hs=hs), chip, "paged_append_token",
         _pool(), _pool(), new, new, _i32(B))
 
 
@@ -85,7 +90,7 @@ def test_paged_append_chunk_kernel(chip, hs):
         paged_append_chunk_kernel
     new = ((2, T, KV // hs, HD), jnp.bfloat16)
     _compile(lambda k, v, kn, vn, s: paged_append_chunk_kernel(
-        (k, v), (kn, vn), s, hs=hs), chip,
+        (k, v), (kn, vn), s, hs=hs), chip, "paged_append_chunk",
         _pool(), _pool(), new, new, _i32(2, T))
 
 
@@ -98,10 +103,43 @@ def test_paged_flash_prefill_kernel(chip, hs, return_lse):
     _compile(lambda q, k, v, t, p: paged_flash_prefill_kernel(
         q, k, v, t, p, hs=hs, blk_q=q_block(128, T, H // hs),
         prior_only=return_lse, return_lse=return_lse), chip,
-        ((2, H // hs, T, HD), dt), _pool(), _pool(), _i32(2, MB), _i32(2))
+        "paged_flash_prefill", ((2, H // hs, T, HD), dt), _pool(), _pool(), _i32(2, MB), _i32(2))
 
 
 def test_flash_prefill_kernel(chip):
     from repro.kernels.flash_prefill.kernel import flash_prefill_kernel
     x = ((1, H, 2048, HD), jnp.bfloat16)
-    _compile(flash_prefill_kernel, chip, x, x, x)
+    _compile(flash_prefill_kernel, chip, "flash_prefill", x, x, x)
+
+
+def test_decode_step_program_is_named_for_its_phase_and_island(chip):
+    """The pool's decode step for a one-engine DP island compiles as
+    module ``jit_fs_decode_n1m1`` with its operations under the scope
+    of the same name, and counts as one build."""
+    from repro.configs import get_config
+    from repro.core.communicator_pool import CommunicatorPool
+    from repro.core.kv_adaptor import PoolGeometry
+    from repro.core.modes import FlyingMode, Island, ParallelPlan
+    from repro.launch.dryrun import abstract_states
+    from repro.models.model import build_model
+    cfg = get_config("stablelm-1.6b-reduced")
+    plan = ParallelPlan(engine_rows=1, tp_base=1, data_rows=1)
+    geom = PoolGeometry(cfg, plan, num_blocks=64, block_base=PAGE)
+    model = build_model(cfg)
+    pool = CommunicatorPool(model, plan, geom)
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=chip)
+    params = jax.tree.map(on_chip, model.param_specs())
+    states = jax.tree.map(on_chip, abstract_states(
+        model, geom, FlyingMode(plan, 1), 4))
+    batch = {k: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)
+             for k, s in (("tokens", (4, 1)), ("positions", (4, 1)),
+                          ("slots", (4,)), ("block_table", (4, 8)),
+                          ("context_len", (4,)))}
+    text = pool.precompile(Island(0, 1, 1), "decode",
+                           (params, states, batch), sampled=True,
+                           donate=True).as_text()
+    assert text.startswith("HloModule jit_fs_decode_n1m1,")
+    assert 'op_name="jit(fs_decode_n1m1)/fs_decode_n1m1/' in text
+    assert pool.stats.compiles == 1
+    assert pool.stats.last_compiled == "fs_decode_n1m1/b4/s1/mb8"
